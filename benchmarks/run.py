@@ -66,6 +66,9 @@ def main() -> None:
                     help="run the dispatch-hygiene analyzer on src/ first "
                          "and refuse to time a dirty tree")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.check:
         # a tree that breaks its own dispatch discipline (host syncs in

@@ -15,13 +15,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import compile_query
 from repro.core.engine import DenseRPQEngine, EngineArrays
-from repro.launch.mesh import mesh_context
+from repro.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh, mesh_context
 from repro.streaming.generators import so_like
 
 
 def main() -> None:
+    enable_compile_cache()
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(model_axis=2)
     dfa = compile_query("a2q . c2a*")
     stream = so_like(n_vertices=48, n_edges=800, seed=9)
 

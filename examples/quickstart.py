@@ -3,6 +3,7 @@ watch answers appear incrementally (Fig. 1 of the paper, end to end).
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from repro.compile_cache import enable_compile_cache
 from repro.core import compile_query
 from repro.core.engine import DenseRPQEngine
 
@@ -25,6 +26,7 @@ STREAM = [
 
 
 def main() -> None:
+    enable_compile_cache()
     dfa = compile_query(QUERY)
     print(f"query {QUERY}: minimal DFA has {dfa.k} states over {dfa.labels}")
     engine = DenseRPQEngine(dfa, window=WINDOW, n_slots=16, batch_size=1)

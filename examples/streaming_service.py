@@ -13,12 +13,14 @@ service ingesting a streaming graph with sliding-window semantics.
 import tempfile
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.streaming.generators import so_like, with_deletions
 from repro.streaming.service import PersistentQueryService
 from repro.streaming.stream import Stream
 
 
 def main() -> None:
+    enable_compile_cache()
     stream = with_deletions(so_like(n_vertices=48, n_edges=900, seed=42),
                             ratio=0.02, seed=1)
     print(f"stream: {len(stream)} sgts over {stream.span()[1]:.0f}s "

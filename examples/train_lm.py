@@ -7,9 +7,11 @@ monitor. The full-size configs are exercised via the multi-pod dry-run
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     if len(sys.argv) == 1:
         sys.argv += ["--arch", "smollm-360m", "--steps", "200", "--batch", "8",
                      "--seq", "128"]

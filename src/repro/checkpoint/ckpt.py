@@ -152,7 +152,7 @@ def save(
     return step_dir
 
 
-_pending: Dict[str, threading.Thread] = {}
+_pending: Dict[str, Tuple[threading.Thread, list]] = {}
 
 
 def async_save(directory: str, step: int, tree: Any,
@@ -165,25 +165,36 @@ def async_save(directory: str, step: int, tree: Any,
     :class:`SimulatedCrash` raised on the background thread is swallowed
     there — exactly like a real process kill between ``async_save`` and
     ``wait_pending``, the save just never commits and the partial tmp dir
-    is left behind for the atomicity contract to neutralize."""
+    is left behind for the atomicity contract to neutralize. Any other
+    failure of the save is re-raised by the next :func:`wait_pending` on
+    ``directory`` (which every later ``async_save`` calls first)."""
     wait_pending(directory)
     host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+    errors: list = []
 
     def _run() -> None:
         try:
             save(directory, step, host_tree, extra, _crash_after=_crash_after)
         except SimulatedCrash:
             pass  # the "process" died mid-save; partial state stays on disk
+        except Exception as e:  # thread boundary: handed to wait_pending
+            errors.append(e)
 
     t = threading.Thread(target=_run)
     t.start()
-    _pending[directory] = t
+    _pending[directory] = (t, errors)
 
 
 def wait_pending(directory: str) -> None:
-    t = _pending.pop(directory, None)
-    if t is not None:
-        t.join()
+    """Join the in-flight :func:`async_save` on ``directory``, re-raising
+    the exception its save failed with (a simulated crash excepted)."""
+    entry = _pending.pop(directory, None)
+    if entry is None:
+        return
+    t, errors = entry
+    t.join()
+    if errors:
+        raise errors[0]
 
 
 def _is_committed(step_dir: str) -> bool:

@@ -54,7 +54,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.executor import (
@@ -177,12 +176,12 @@ def make_sharded_closure(mesh: Mesh, backend,
         )
         return d_f, rounds.reshape(1), qrounds
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(dist_spec, P(None, model_axis, None), P(None, None, model_axis),
                   *_row_specs(qa), P(qa), P(), P()),
         out_specs=(dist_spec, P(qa), P(qa)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -211,12 +210,12 @@ def make_sharded_frontier_closure(mesh: Mesh, backend, f_cap: int,
         return (d_f, rounds.reshape(1), qrounds, rr.reshape(1),
                 fb.reshape(1), seed.reshape(1), mx.reshape(1))
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(dist_spec, P(None, model_axis, None), P(None, None, model_axis),
                   *_row_specs(qa), P(qa), P(None), P(None), P(), P()),
         out_specs=(dist_spec, P(qa), P(qa), P(qa), P(qa), P(qa), P(qa)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -245,12 +244,12 @@ def make_sharded_frontier_delete(mesh: Mesh, backend, f_cap: int,
         return (d_f, rounds.reshape(1), qrounds, rr.reshape(1),
                 fb.reshape(1), seed.reshape(1), mx.reshape(1))
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(dist_spec, P(None, model_axis, None), P(None, None, model_axis),
                   *_row_specs(qa), P(qa), P(None), P(None), P(), P()),
         out_specs=(dist_spec, P(qa), P(qa), P(qa), P(qa), P(qa), P(qa)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -285,12 +284,12 @@ def make_sharded_round(mesh: Mesh, backend,
 
         return jax.lax.cond(jnp.any(mask0), run, lambda _: dist_blk, None)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(dist_spec, P(None, model_axis, None), P(None, None, model_axis),
                   *_row_specs(qa), P(qa), P(), P()),
         out_specs=dist_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -325,12 +324,12 @@ def make_sharded_frontier_round(mesh: Mesh, backend,
 
         return jax.lax.cond(jnp.any(rowmask), run, lambda _: dist_blk, None)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(dist_spec, P(None, model_axis, None), P(None, None, model_axis),
                   *_row_specs(qa), P(qa, None), P(qa, None), P(), P()),
         out_specs=dist_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -1,13 +1,18 @@
 """Fused Pallas gather-contract over padded-ELL adjacency rows.
 
 Grid ``(J, M/bm, U/bu)`` with the u-axis innermost: each step loads a
-``(bm, bu)`` block of the row operand and the ``(bu, E)`` ELL slot
-block for transition ``j``, then walks the ``bu * E`` slots performing
-``o[:, idx[u, e]] = max(o[:, idx[u, e]], min(d[:, u], ts[u, e]))`` via
-single-column ``pl.ds`` read-modify-writes.  The output block spans the
+``(bu, bm)`` block of the row operand, stored u-major, and the ``bu * E``
+ELL slots of transition ``j`` for that u-block, then walks the slots
+performing ``o[idx[u, e], :] = max(o[idx[u, e], :], min(d[u, :], ts[u, e]))``
+via single-row ``pl.ds`` read-modify-writes.  The output block spans the
 full vertex width and is revisited across the u-grid (the same
 accumulator pattern as the k-loop in ``kernels/maxmin``), initialized
 to ``zero`` at the first u-step with ``pl.when``.
+
+The operand and the output are u-major / v-major (the wrapper transposes
+on the way in and out) so the per-slot dynamic index lands on the
+sublane axis, which Mosaic can address per row; the slot tables ride in
+SMEM, where per-slot scalar reads are native.
 
 Block sizes come from the shared ``pick_block_sizes`` table (rule R3);
 the scatter axis cannot be blocked, so only (m, u) tile.  Free slots
@@ -22,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..maxmin.maxmin import pick_block_sizes
 
@@ -32,27 +38,18 @@ def _r8(x: int) -> int:
     return max(x + (-x) % 8, 8)
 
 
-def _r128(x: int) -> int:
-    return max(x + (-x) % 128, 128)
-
-
-def _ell_kernel(d_ref, idx_ref, ts_ref, o_ref, *, bu, e_cap, zero):
+def _ell_kernel(idx_ref, ts_ref, d_ref, o_ref, *, bu, e_cap, zero):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full(o_ref.shape, zero, o_ref.dtype)
 
-    d = d_ref[0]                      # (bm, bu)
-    idx_flat = idx_ref[0].reshape(-1)  # (bu * e_cap,) int32
-    ts_flat = ts_ref[0].reshape(-1)
-
     def body(i, _):
-        col = lax.dynamic_index_in_dim(idx_flat, i, keepdims=False)
-        t = lax.dynamic_index_in_dim(ts_flat, i, keepdims=False)
-        u = i // e_cap
-        d_col = lax.dynamic_slice(d, (0, u), (d.shape[0], 1))[:, 0]
-        cand = jnp.minimum(d_col, t.astype(d.dtype))
-        cur = o_ref[0, :, pl.ds(col, 1)]
-        o_ref[0, :, pl.ds(col, 1)] = jnp.maximum(cur, cand[:, None])
+        col = idx_ref[0, 0, i]                        # SMEM scalars
+        t = ts_ref[0, 0, i]
+        d_row = d_ref[0, pl.ds(i // e_cap, 1), :]     # (1, bm)
+        cand = jnp.minimum(d_row, t.astype(d_row.dtype))
+        cur = o_ref[0, pl.ds(col, 1), :]
+        o_ref[0, pl.ds(col, 1), :] = jnp.maximum(cur, cand)
         return 0
 
     lax.fori_loop(0, bu * e_cap, body, 0)
@@ -75,23 +72,28 @@ def ell_gather_contract_fused(d, idx, ts, *, zero=NEG_INF, bm=None, bu=None,
 
     m_pad = m + (-m) % bm
     u_pad = u + (-u) % bu
-    n_out = _r128(u)
+    n_out = _r8(u)
     zval = jnp.asarray(zero, d.dtype)
-    d_p = jnp.full((j, m_pad, u_pad), zval, d.dtype).at[:, :m, :u].set(d)
+    d_t = jnp.full((j, u_pad, m_pad), zval, d.dtype).at[:, :u, :m].set(
+        jnp.swapaxes(d, 1, 2))
     idx_p = jnp.zeros((j, u_pad, e_cap), jnp.int32).at[:, :u, :].set(idx)
     ts_p = jnp.full((j, u_pad, e_cap), jnp.asarray(zero, ts.dtype),
                     ts.dtype).at[:, :u, :].set(ts)
+    slots = (1, 1, bu * e_cap)
 
     out = pl.pallas_call(
         functools.partial(_ell_kernel, bu=bu, e_cap=e_cap, zero=zero),
         grid=(j, m_pad // bm, u_pad // bu),
         in_specs=[
-            pl.BlockSpec((1, bm, bu), lambda ji, mi, ui: (ji, mi, ui)),
-            pl.BlockSpec((1, bu, e_cap), lambda ji, mi, ui: (ji, ui, 0)),
-            pl.BlockSpec((1, bu, e_cap), lambda ji, mi, ui: (ji, ui, 0)),
+            pl.BlockSpec(slots, lambda ji, mi, ui: (ji, 0, ui),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(slots, lambda ji, mi, ui: (ji, 0, ui),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bu, bm), lambda ji, mi, ui: (ji, ui, mi)),
         ],
-        out_specs=pl.BlockSpec((1, bm, n_out), lambda ji, mi, ui: (ji, mi, 0)),
-        out_shape=jax.ShapeDtypeStruct((j, m_pad, n_out), d.dtype),
+        out_specs=pl.BlockSpec((1, n_out, bm), lambda ji, mi, ui: (ji, 0, mi)),
+        out_shape=jax.ShapeDtypeStruct((j, n_out, m_pad), d.dtype),
         interpret=interpret,
-    )(d_p, idx_p, ts_p)
-    return out[:, :m, :u]
+    )(idx_p.reshape(j, 1, u_pad * e_cap), ts_p.reshape(j, 1, u_pad * e_cap),
+      d_t)
+    return jnp.swapaxes(out[:, :u, :m], 1, 2)

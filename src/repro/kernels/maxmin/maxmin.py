@@ -6,9 +6,9 @@ TPU mapping notes (DESIGN.md §2): the (max, min) semiring has no MXU
 contraction, so this runs on the VPU; the kernel's job is the memory
 schedule — HBM→VMEM tiling with a k-innermost accumulation grid so each
 output tile stays resident in VMEM across k-steps. Block sizes keep the
-(bm, bk, bn) broadcast intermediate within VMEM (bm*bk*bn*4B + tiles
-≲ 8 MiB of the ~16 MiB/core budget), and bm/bn are 128-aligned for lane
-efficiency.
+(bm, bk, bn) broadcast intermediate within VMEM (bm*bk*bn*4B ≤ 8 MiB of
+the 16 MiB scoped budget), and bk/bn are 128-aligned (Mosaic's lane
+tiling).
 
 The MXU-friendly alternative (bucketized boolean closure, used by the
 engine's ``mxu_bucket`` mode) lives in ``kernels/bucket``.
@@ -23,16 +23,21 @@ from jax.experimental import pallas as pl
 
 NEG_INF = float("-inf")
 
-# Shape-aware block-size table (PR 5 satellite): rows keyed by the M extent
-# of the contraction. The dense round's operands are square-ish (M = N), but
-# the frontier-restricted round feeds SKINNY (F, N) slabs — a fixed 128-row
+# Shape-aware block-size table: rows keyed by the M extent of the
+# contraction. The dense round's operands are square-ish (M = N), but the
+# frontier-restricted round feeds SKINNY (F, N) slabs — a fixed 128-row
 # block would pad a F=16 slab 8x and waste 7/8 of every VPU tile. Small-M
 # rows trade bm down and bn up (the broadcast intermediate bm*bk*bn*4B stays
-# ≲ 8 MiB of VMEM either way); bn keeps the 128-lane alignment. The M<=4 row
-# serves the row-sparse dist gather (PR 9): a Q·F row slab at tiny frontiers
-# is a handful of rows against a WIDE N·K entry axis, so bn doubles again —
-# the sweep over the entry axis halves its grid steps while bm*bn*4B stays
-# a single VMEM tile.
+# ≤ 8 MiB of the 16 MiB scoped VMEM either way). The M<=4 row serves the
+# row-sparse dist gather: a Q·F row slab at tiny frontiers is a handful of
+# rows against a WIDE N·K entry axis, so bn doubles again — the sweep over
+# the entry axis halves its grid steps while bm*bn*4B stays a single VMEM
+# tile.
+#
+# Mosaic tiles the last two block dims by (8, 128): bk is the LANE dim of
+# the A block and bn of the B/output blocks, so both are multiples of 128
+# (or clamp to the whole padded axis, which is also legal); bm and bk as
+# sublane dims are multiples of 8.
 _BLOCK_TABLE = (
     # (max M, (bm, bn, bk))
     (4,    (8, 512, 128)),
@@ -40,7 +45,7 @@ _BLOCK_TABLE = (
     (16,   (16, 256, 128)),
     (32,   (32, 256, 128)),
     (64,   (64, 128, 128)),
-    (None, (128, 128, 64)),
+    (None, (128, 128, 128)),
 )
 
 

@@ -1,12 +1,16 @@
 """Fused Pallas gather for row-sparse dist rows.
 
-Grid ``(M/bm, E/bn)``: each step owns a ``(bm, bn)`` output tile and
-the full ``(bm, C)`` slot block of its rows (slot capacity C is small —
+Grid ``(M/bm, E/bn)``: each step owns a ``(bn, bm)`` output tile and
+the full ``(C, bm)`` slot block of its rows (slot capacity C is small —
 it is the pow2 ``dist_cap`` — so the block always fits VMEM).  The
 kernel sweeps the C slots with a ``fori_loop``, comparing each slot's
-flattened key against the tile's column range and max-folding the hits:
-a compare-select per slot on a (bm, bn) vector register, never a
+flattened key against the tile's entry range and max-folding the hits:
+a compare-select per slot on a (bn, bm) vector register, never a
 (bm, C, bn) broadcast, so VMEM stays O(bm * (C + bn)) at any capacity.
+
+Slots and the output are slot-major / entry-major (the wrapper
+transposes on the way in and out) so the per-slot dynamic read is one
+sublane row, which Mosaic can address; the rows ``m`` run along lanes.
 
 Every output tile is visited exactly once (no accumulation grid dim),
 so no ``pl.when`` init is needed.  Free slots carry ``ts == zero`` and
@@ -16,8 +20,8 @@ as the other semiring kernels (padding is the semiring zero).
 
 Block sizes come from the shared ``pick_block_sizes`` table (rule R3);
 the skinny (rows, E) shapes this kernel sees — a handful of gathered
-frontier rows against E = N*K columns — are the narrow-m rows PR 9
-added to the table.
+frontier rows against E = N*K columns — are the narrow-m rows of the
+table.
 """
 from __future__ import annotations
 
@@ -38,18 +42,15 @@ def _r8(x: int) -> int:
 
 
 def _rs_kernel(idx_ref, ts_ref, o_ref, *, bn, c_cap, zero):
-    col0 = pl.program_id(1) * bn
-    idxb = idx_ref[...]                     # (bm, C)
-    tsb = ts_ref[...]
-    cols = (lax.broadcasted_iota(jnp.int32, (o_ref.shape[0], bn), 1)
-            + col0)                          # (bm, bn) global column ids
+    row0 = pl.program_id(1) * bn
+    ents = (lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+            + row0)                          # (bn, bm) global entry ids
+    zval = jnp.asarray(zero, o_ref.dtype)
 
     def body(c, acc):
-        key = lax.dynamic_slice(idxb, (0, c), (idxb.shape[0], 1))  # (bm, 1)
-        val = lax.dynamic_slice(tsb, (0, c), (tsb.shape[0], 1))
-        cand = jnp.where(key == cols, val.astype(acc.dtype),
-                         jnp.asarray(zero, acc.dtype))
-        return jnp.maximum(acc, cand)
+        key = idx_ref[pl.ds(c, 1), :]        # (1, bm)
+        val = ts_ref[pl.ds(c, 1), :].astype(acc.dtype)
+        return jnp.maximum(acc, jnp.where(key == ents, val, zval))
 
     o_ref[...] = lax.fori_loop(
         0, c_cap, body, jnp.full(o_ref.shape, zero, o_ref.dtype))
@@ -70,19 +71,19 @@ def rowsparse_gather_fused(idx, ts, e: int, *, zero=NEG_INF, bm=None,
 
     m_pad = m + (-m) % bm
     e_pad = e + (-e) % bn
-    idx_p = jnp.zeros((m_pad, c_cap), jnp.int32).at[:m].set(idx)
-    ts_p = jnp.full((m_pad, c_cap), jnp.asarray(zero, ts.dtype),
-                    ts.dtype).at[:m].set(ts)
+    idx_t = jnp.zeros((c_cap, m_pad), jnp.int32).at[:, :m].set(idx.T)
+    ts_t = jnp.full((c_cap, m_pad), jnp.asarray(zero, ts.dtype),
+                    ts.dtype).at[:, :m].set(ts.T)
 
     out = pl.pallas_call(
         functools.partial(_rs_kernel, bn=bn, c_cap=c_cap, zero=zero),
         grid=(m_pad // bm, e_pad // bn),
         in_specs=[
-            pl.BlockSpec((bm, c_cap), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, c_cap), lambda i, j: (i, 0)),
+            pl.BlockSpec((c_cap, bm), lambda i, j: (0, i)),
+            pl.BlockSpec((c_cap, bm), lambda i, j: (0, i)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, e_pad), ts.dtype),
+        out_specs=pl.BlockSpec((bn, bm), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((e_pad, m_pad), ts.dtype),
         interpret=interpret,
-    )(idx_p, ts_p)
-    return out[:m, :e]
+    )(idx_t, ts_t)
+    return out[:e, :m].T

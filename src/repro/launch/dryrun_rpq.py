@@ -130,12 +130,11 @@ def make_ring_round(mesh, tt: TransitionTable, n_slots: int, multi_pod: bool):
         out_blk = jax.lax.fori_loop(0, tp - 1, hop, acc0)
         return jnp.maximum(dist_blk, out_blk)
 
-    from jax.experimental.shard_map import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(xa, "model", None), P(None, "model", None)),
         out_specs=P(xa, "model", None),
-        check_rep=False,
+        check_vma=False,
     )
 
 

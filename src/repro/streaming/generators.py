@@ -23,16 +23,16 @@ def so_like(n_vertices: int, n_edges: int, seed: int = 0,
     """StackOverflow-style: one vertex type, 3 interaction labels, heavy
     preferential attachment -> dense cyclic core."""
     rng = random.Random(seed)
-    degree = [1] * n_vertices
+    degree = _DegreeTree(n_vertices)
     tuples = []
     t = 0.0
     for _ in range(n_edges):
         t += rng.expovariate(rate)
         # preferential attachment on both endpoints
-        u = _weighted(rng, degree)
-        v = _weighted(rng, degree)
-        degree[u] += 1
-        degree[v] += 1
+        u = degree.draw(rng)
+        v = degree.draw(rng)
+        degree.add(u)
+        degree.add(v)
         tuples.append(SGT(t, u, v, rng.choice(SO_LABELS)))
     return Stream(tuples)
 
@@ -277,12 +277,37 @@ def churn_storm_plan(n_batches: int, seed: int = 0,
     return plan
 
 
-def _weighted(rng: random.Random, weights: List[int]) -> int:
-    total = sum(weights)
-    r = rng.random() * total
-    acc = 0
-    for i, w in enumerate(weights):
-        acc += w
-        if r <= acc:
-            return i
-    return len(weights) - 1
+class _DegreeTree:
+    """Integer vertex weights (all starting at 1) in a Fenwick tree, so a
+    preferential-attachment draw is O(log n) instead of a linear scan.
+
+    :meth:`draw` consumes one ``rng.random()`` and returns the smallest
+    vertex whose inclusive prefix weight reaches ``r = random() * total``:
+    the same vertex the linear scan ``acc += w; if r <= acc`` picks, since
+    the prefix sums are exact integers compared against the same float."""
+
+    def __init__(self, n: int):
+        self.n = n
+        self.total = n
+        # node i covers the (i & -i) weights ending at i, all of them 1
+        self._tree = [i & -i for i in range(n + 1)]
+        self._top = 1 << max(n.bit_length() - 1, 0)
+
+    def add(self, i: int) -> None:
+        """Raise vertex ``i``'s weight by one."""
+        self.total += 1
+        i += 1
+        while i <= self.n:
+            self._tree[i] += 1
+            i += i & -i
+
+    def draw(self, rng: random.Random) -> int:
+        r = rng.random() * self.total
+        pos, acc, step = 0, 0, self._top
+        while step:
+            nxt = pos + step
+            if nxt <= self.n and acc + self._tree[nxt] < r:
+                pos = nxt
+                acc += self._tree[nxt]
+            step >>= 1
+        return min(pos, self.n - 1)

@@ -705,6 +705,11 @@ class PersistentQueryService:
                     res = eng.insert(sgt.src, sgt.dst, sgt.label, sgt.ts)
                     new_results[name] |= res
                 else:
+                    # expire to the deletion's own clock first, as the
+                    # dense delete judges validity at it: a pair the
+                    # window already dropped is not invalidated by the
+                    # negative tuple (lazy expiry would report it here)
+                    eng.expire(sgt.ts)
                     inv = eng.delete(sgt.src, sgt.dst, sgt.label, sgt.ts)
                     if inv:
                         invalidated[name] |= set(inv)
@@ -818,6 +823,9 @@ class PersistentQueryService:
             ckpt.async_save(directory, step, state, extra=extra,
                             _crash_after=_crash_after)
         else:
+            # an async save still writing to `directory` may target the
+            # same step dir: join it first so the two never interleave
+            ckpt.wait_pending(directory)
             ckpt.save(directory, step, state, extra=extra,
                       _crash_after=_crash_after)
 

@@ -32,12 +32,13 @@ PersistentQueryService` with the machinery that turns "fast on gmark" into
   actually saw).
 
 * **Graceful degradation** — a :class:`CircuitBreaker` watches the
-  per-interval overflow-drain rate (frontier fallbacks + ELL spill drains
-  + row-sparse dist drains). When pressure exceeds the trip threshold the
-  supervisor performs a controlled handover onto the dense fallbacks
-  (``frontier="off"``, ``adj_layout="dense"``, ``dist_layout="dense"``)
-  via sync-snapshot → rebuild → restore (canonical-dense checkpoints make
-  this loss-free), and re-arms back to the preferred sparse config after a
+  per-interval overflow rate (frontier fallbacks + ELL and row-sparse
+  re-packs, i.e. drains that found overflow or grew a capacity). When
+  pressure exceeds the trip threshold the supervisor performs a
+  controlled handover onto the dense fallbacks (``frontier="off"``,
+  ``adj_layout="dense"``, ``dist_layout="dense"``) via sync-snapshot →
+  rebuild → restore (canonical-dense checkpoints make this loss-free),
+  and re-arms back to the preferred sparse config after a
   quiet period. Per-interval telemetry rides :attr:`health_log` in the
   same ``*_log`` pattern as the service's frontier/adjacency/dist logs.
 
@@ -602,12 +603,11 @@ class ServiceSupervisor:
         ex = group.executor
         out = {"frontier_fallbacks": int(
             ex.frontier_stats.get("fallbacks", 0))}
-        astats = ex.adjacency_stats
-        out["adj_spill_drains"] = int(astats.get("spill_drains", 0))
-        out["adj_repacks"] = int(astats.get("repacks", 0))
-        dstats = ex.dist_stats
-        out["dist_drains"] = int(dstats.get("drains", 0))
-        out["dist_repacks"] = int(dstats.get("repacks", 0))
+        # re-packs, not drains: a drain is a budget check that syncs the
+        # ring/table cursor and usually finds nothing; one that finds
+        # overflow (or a capacity growth) re-packs, and that is the pressure
+        out["adj_repacks"] = int(ex.adjacency_stats.get("repacks", 0))
+        out["dist_repacks"] = int(ex.dist_stats.get("repacks", 0))
         return out
 
     def _flush_health(self) -> None:

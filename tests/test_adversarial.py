@@ -69,6 +69,22 @@ def test_deletion_storm_contract():
     assert n_del >= 0.15 * 150
 
 
+def test_so_like_stream_pinned():
+    """The O(log n) preferential-attachment draw reproduces the streams the
+    linear-scan draw produced, bit for bit (values recorded from it)."""
+    s = [(x.ts, x.src, x.dst, x.label, x.op)
+         for x in so_like(1000, 2000, seed=5)]
+    assert len(s) == 2000
+    assert s[:3] == [(0.09752493692643936, 741, 795, "c2q", "+"),
+                     (0.20308932552471554, 531, 840, "a2q", "+"),
+                     (0.3077789815205892, 900, 113, "c2a", "+")]
+    assert s[1995:] == [(195.4688926893313, 303, 835, "a2q", "+"),
+                        (195.47629673826117, 591, 919, "a2q", "+"),
+                        (195.55715434247006, 604, 498, "c2q", "+"),
+                        (196.00579370645423, 622, 822, "c2q", "+"),
+                        (196.03695899113814, 899, 176, "a2q", "+")]
+
+
 def test_mixed_window_streams_span_100x():
     entries = mixed_window_streams(24, 60, seed=1)
     windows = [e["window"] for e in entries]

@@ -175,6 +175,26 @@ def test_crash_between_async_save_and_wait_pending_falls_back():
             assert svc3.results(name) == mid_results[name], name
 
 
+def test_sync_snapshot_joins_in_flight_async_save():
+    """A synchronous snapshot to a directory with an async save still in
+    flight (the breaker's handover lands on the same step as the periodic
+    snapshot) joins that save first, so the two never write the same
+    step's tmp dir at once; the later one is what LATEST publishes."""
+    tuples = _stream_tuples()
+    svc = _make_service()
+    svc.ingest(Stream(tuples[:40]))
+    with tempfile.TemporaryDirectory() as d:
+        svc.snapshot(d, step=4, async_save=True)
+        svc.snapshot(d, step=4)
+        assert d not in ckpt._pending
+        assert ckpt.latest_step_dir(d).endswith("step_000000004")
+        assert not any(".tmp" in n for n in os.listdir(d))
+        svc2 = _make_service()
+        assert svc2.restore(d) == 4
+        for name in QUERY_NAMES:
+            assert svc2.results(name) == svc.results(name), name
+
+
 # -- snapshot vs async-decode FIFO (ISSUE 10 satellite) -----------------------
 
 

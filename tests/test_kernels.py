@@ -149,7 +149,7 @@ def test_pick_block_sizes_table():
     assert pick_block_sizes(8, 512, 512) == (8, 256, 128)
     assert pick_block_sizes(16, 512, 512) == (16, 256, 128)
     assert pick_block_sizes(32, 512, 512) == (32, 256, 128)
-    assert pick_block_sizes(512, 512, 512) == (128, 128, 64)
+    assert pick_block_sizes(512, 512, 512) == (128, 128, 128)
     # ultra-skinny row slabs (the row-sparse dist gather: a handful of
     # (q, x) rows against a wide N·K entry axis) double bn again
     assert pick_block_sizes(4, 512, 2048) == (8, 512, 128)

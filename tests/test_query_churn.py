@@ -325,6 +325,20 @@ def test_service_live_lifecycle_and_invalidations():
     assert svc.results("r") == svc.results("d")
 
 
+def test_deletion_does_not_invalidate_window_expired_pairs():
+    """A negative tuple between slide boundaries invalidates only pairs
+    still valid at its own clock, on both engines: (1, 2) and (1, 3) fell
+    out of the window (low = 2) before the delete, so only (2, 3) is
+    reported, although the reference's lazy expiry still holds them."""
+    svc = PersistentQueryService(window=10.0, slide=100.0)
+    svc.register("d", "a . a*", engine="dense", n_slots=16)
+    svc.register("r", "a . a*", engine="reference")
+    svc.ingest(Stream([SGT(1.0, 1, 2, "a"), SGT(5.0, 2, 3, "a")]))
+    rep = svc.ingest(Stream([SGT(12.0, 2, 3, "a", "-")]))
+    assert rep.invalidated["d"] == {(2, 3)}
+    assert rep.invalidated["r"] == {(2, 3)}
+
+
 def test_first_dense_registration_mid_stream_starts_tracking():
     """The FIRST dense query arriving after ingestion started has no dense
     group to seed from (prefix content was only seen by reference engines):

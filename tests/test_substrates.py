@@ -109,6 +109,23 @@ def test_checkpoint_async_then_restore():
         assert extra["step"] == 1
 
 
+def test_checkpoint_async_save_failure_reraised_at_wait_pending():
+    """A real IO failure on the saver thread is not lost: wait_pending
+    re-raises it (only the fault harness's SimulatedCrash is swallowed),
+    and the slot is cleared so the next save starts clean."""
+    from repro.checkpoint import ckpt
+
+    with tempfile.TemporaryDirectory() as d:
+        blocker = os.path.join(d, "blocker")
+        with open(blocker, "w") as f:
+            f.write("not a directory")
+        target = os.path.join(blocker, "ckpt")
+        ckpt.async_save(target, 1, {"w": jnp.ones((4,))})
+        with pytest.raises(OSError):
+            ckpt.wait_pending(target)
+        ckpt.wait_pending(target)  # already surfaced: nothing pending
+
+
 def test_run_with_restarts_recovers_from_crash():
     from repro.distributed.fault import run_with_restarts
 

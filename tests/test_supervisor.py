@@ -419,6 +419,36 @@ def test_breaker_trips_to_dense_and_preserves_results():
         assert any(h.get("degraded") for h in sup.health_log)
 
 
+def _roomy_sparse_service(**overrides):
+    # capacities that never overflow this stream: every ELL/dist budget
+    # drain finds nothing, no frontier falls back, nothing re-packs
+    kw = dict(window=WINDOW, slide=SLIDE, frontier="on", frontier_cap=64,
+              adj_layout="ell", ell_cap=64, dist_layout="row_sparse",
+              dist_cap=128)
+    kw.update(overrides)
+    svc = PersistentQueryService(**kw)
+    svc.register("d_arb", "a2q . c2a*", engine="dense", n_slots=48)
+    svc.register("d_plus", "(a2q | c2a)+", engine="dense", n_slots=48)
+    return svc
+
+
+def test_breaker_ignores_empty_budget_drains():
+    """Budget drains that find the spill ring / overflow table empty are
+    bookkeeping syncs, not overflow: they must not trip the breaker."""
+    with tempfile.TemporaryDirectory() as d:
+        sup = ServiceSupervisor(
+            _roomy_sparse_service, d, batch_events=8, ckpt_every=4,
+            health_every=2, breaker=CircuitBreaker(trip_threshold=0.25))
+        sup.run(_stream_tuples())
+        ex = sup.service._group.executor
+        assert ex.dist_stats["drains"] > 0
+        assert ex.dist_stats["repacks"] == 0
+        assert ex.adjacency_stats["repacks"] == 0
+        assert ex.frontier_stats["fallbacks"] == 0
+        assert not sup.breaker.log
+        assert all(h["overflow_events"] == 0 for h in sup.health_log)
+
+
 def test_breaker_rearms_after_quiet_period():
     tuples = _stream_tuples()
     clean_final, _, _ = _clean_run(tuples, _overflowy_service,
