@@ -6,13 +6,15 @@ service ingesting a streaming graph with sliding-window semantics.
 * ingests with eager evaluation / lazy expiration (slide interval beta),
 * injects explicit deletions (negative tuples),
 * checkpoints engine state mid-stream and proves re-attach works,
-* prints per-query throughput/latency/result stats.
+* prints per-query result stats, and the service's ingest-call times
+  from the in-program recorder (``repro.telemetry``).
 
     PYTHONPATH=src python examples/streaming_service.py
 """
 import tempfile
 import time
 
+from repro import telemetry
 from repro.compile_cache import enable_compile_cache
 from repro.streaming.generators import so_like, with_deletions
 from repro.streaming.service import PersistentQueryService
@@ -35,7 +37,7 @@ def main() -> None:
     tuples = list(stream)
     half = len(tuples) // 2
     t0 = time.perf_counter()
-    svc.ingest(Stream(tuples[:half]), record_latency=True)
+    svc.ingest(Stream(tuples[:half]))
 
     # --- mid-stream checkpoint + re-attach (fault tolerance drill) ---------
     with tempfile.TemporaryDirectory() as ckpt_dir:
@@ -50,13 +52,16 @@ def main() -> None:
         print(f"[ckpt] snapshot + re-attach at sgt {half}: OK "
               f"({len(svc.results('notify'))} results preserved)")
 
-    svc.ingest(Stream(tuples[half:]), record_latency=True)
+    svc.ingest(Stream(tuples[half:]))
     wall = time.perf_counter() - t0
 
     print(f"\ningested {len(tuples)} sgts in {wall:.2f}s "
           f"({len(tuples)/wall:.0f} sgts/s aggregate)")
+    calls = telemetry.summary()["spans"]["service.ingest"]
+    print(f"  service.ingest: {calls['count']} calls, "
+          f"p95 {calls['p95_ms']:.1f} ms, max {calls['max_ms']:.1f} ms")
     for name, st in svc.stats.items():
-        print(f"  {name:15s} results={st.results:6d} p99={st.p99_us:8.0f}us "
+        print(f"  {name:15s} results={st.results:6d} "
               f"conflicted={st.conflicted}")
 
 

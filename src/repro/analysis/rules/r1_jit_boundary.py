@@ -21,6 +21,9 @@ Flagged inside jit-reachable functions:
   of tuples/lists by name stays legal
 * Python ``if`` whose test calls into ``jnp.*`` — a tracer boolean;
   inside jit this must be ``lax.cond``/``jnp.where``
+* ``telemetry.*`` calls (the host recorder, ``repro.telemetry``): under
+  trace a span times the tracing, once, never the device's work — name
+  a traced phase with ``jax.named_scope`` instead
 
 The call graph is described in :mod:`repro.analysis.analyzer`; attribute
 calls (backend method dispatch) are not traversed.
@@ -70,6 +73,11 @@ def check(project: Project) -> Iterator[Finding]:
             if (isinstance(func, ast.Attribute) and func.attr == "item"
                     and not n.args):
                 yield _finding(mod, n, qual, "host sync `.item()`")
+            elif dotted(func).startswith("telemetry."):
+                yield _finding(
+                    mod, n, qual,
+                    f"host recorder call `{dotted(func)}` runs once at "
+                    "trace time — use jax.named_scope")
             elif (isinstance(func, ast.Attribute)
                   and isinstance(func.value, ast.Name)
                   and func.value.id in _NP_NAMES

@@ -32,6 +32,8 @@ import jax
 import ml_dtypes
 import numpy as np
 
+from .. import telemetry
+
 SEP = "/"
 
 
@@ -171,10 +173,15 @@ def async_save(directory: str, step: int, tree: Any,
     wait_pending(directory)
     host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
     errors: list = []
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(host_tree))
+    batch_id = telemetry.current_batch()
 
     def _run() -> None:
         try:
-            save(directory, step, host_tree, extra, _crash_after=_crash_after)
+            with telemetry.batch(batch_id), \
+                    telemetry.span("checkpoint.write", nbytes):
+                save(directory, step, host_tree, extra,
+                     _crash_after=_crash_after)
         except SimulatedCrash:
             pass  # the "process" died mid-save; partial state stays on disk
         except Exception as e:  # thread boundary: handed to wait_pending
@@ -192,7 +199,8 @@ def wait_pending(directory: str) -> None:
     if entry is None:
         return
     t, errors = entry
-    t.join()
+    with telemetry.span("checkpoint.join"):
+        t.join()
     if errors:
         raise errors[0]
 

@@ -125,6 +125,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from .automaton import DFA
 from .executor import (
     BatchedEngineArrays,
@@ -200,6 +201,16 @@ class RegisteredQuery(NamedTuple):
     path_semantics: str = "arbitrary"  # arbitrary | simple
 
 
+def _fetch_result(result_dev) -> np.ndarray:
+    """A dispatch's result mask on the host: wait for the dispatch to
+    finish (``engine.result_wait``), then copy the mask
+    (``engine.result_copy``, valued in bytes)."""
+    with telemetry.span("engine.result_wait"):
+        jax.block_until_ready(result_dev)
+    with telemetry.span("engine.result_copy", result_dev.nbytes):
+        return np.asarray(result_dev)
+
+
 class PendingResults:
     """Deferred result decoding for one :meth:`insert_batch_pending` call.
 
@@ -225,7 +236,7 @@ class PendingResults:
     def _decode_chunks(self) -> None:
         for new_dev, vertex_of, t in self._chunks:
             self._engine._decode_new_into(
-                np.asarray(new_dev), vertex_of, t, self._fresh)
+                _fetch_result(new_dev), vertex_of, t, self._fresh)
         self._chunks.clear()
         self._decoded = True
 
@@ -596,23 +607,25 @@ class BatchedDenseRPQEngine:
         j = 0
         self._chunk_pinned.clear()
         try:
-            for (u, v, label, t) in edges:
-                li = self._label_index.get(label)
-                if li is None:
-                    continue  # outside the union Sigma_Q: discarded (paper §5.2)
-                # pin each slot as soon as it is interned: _slot() may
-                # compact mid-chunk, and a chunk-local vertex with no
-                # adjacency yet must not be recycled before its edge lands
-                si = self._slot(u)
-                self._chunk_pinned.add(si)
-                di = self._slot(v)
-                self._chunk_pinned.add(di)
-                src[j] = si
-                dst[j] = di
-                lab[j] = li
-                ts[j] = t
-                mask[j] = True
-                j += 1
+            with telemetry.span("engine.intern") as sp:
+                for (u, v, label, t) in edges:
+                    li = self._label_index.get(label)
+                    if li is None:
+                        continue  # outside the union Sigma_Q: discarded (paper §5.2)
+                    # pin each slot as soon as it is interned: _slot() may
+                    # compact mid-chunk, and a chunk-local vertex with no
+                    # adjacency yet must not be recycled before its edge lands
+                    si = self._slot(u)
+                    self._chunk_pinned.add(si)
+                    di = self._slot(v)
+                    self._chunk_pinned.add(di)
+                    src[j] = si
+                    dst[j] = di
+                    lab[j] = li
+                    ts[j] = t
+                    mask[j] = True
+                    j += 1
+                sp.value = j
             self._host_now = max(self._host_now, chunk_now)
             if j == 0:
                 # still advance the clock
@@ -679,22 +692,24 @@ class BatchedDenseRPQEngine:
         chunk_now = max(t for (_u, _v, _l, t) in edges)
         self._host_now = max(self._host_now, chunk_now)
         j = 0
-        for (u, v, label, _t) in edges:
-            li = self._label_index.get(label)
-            if li is None or u not in self.slot_of or v not in self.slot_of:
-                continue  # unknown label/vertex: nothing retained to drop
-            src[j] = self.slot_of[u]
-            dst[j] = self.slot_of[v]
-            lab[j] = li
-            mask[j] = True
-            j += 1
+        with telemetry.span("engine.intern") as sp:
+            for (u, v, label, _t) in edges:
+                li = self._label_index.get(label)
+                if li is None or u not in self.slot_of or v not in self.slot_of:
+                    continue  # unknown label/vertex: nothing retained to drop
+                src[j] = self.slot_of[u]
+                dst[j] = self.slot_of[v]
+                lab[j] = li
+                mask[j] = True
+                j += 1
+            sp.value = j
         if j == 0:
             # still advance the clock (every event timestamp moves it)
             self.executor.advance_clock(chunk_now)
             return
         invalidated = self.executor.delete_batch(
             src, dst, lab, mask, chunk_now, self.tables)
-        inv = np.asarray(invalidated)
+        inv = _fetch_result(invalidated)
         for qi, _spec in self.live_items():
             out[qi] |= self._decode_pairs(inv[qi], bool(self._simple[qi]))
 
@@ -750,20 +765,30 @@ class BatchedDenseRPQEngine:
         after slot recycling the emitted matrices forget old occupants, so
         the device diff may resurface already-reported pairs — the
         python-side sets are the source of truth for implicit-window
-        monotonicity."""
-        qs, xs, vs = np.nonzero(arr)
-        for q, x, v in zip(qs.tolist(), xs.tolist(), vs.tolist()):
-            if self._simple[q] and x == v:
-                continue
-            xv = vertex_of[x]
-            vv = vertex_of[v]
-            if xv is None or vv is None:
-                continue
-            p = (xv, vv)
-            if p not in self.per_query_results[q]:
-                self.per_query_results[q].add(p)
-                self.per_query_log[q].append((t, p))
-                fresh[q].add(p)
+        monotonicity.
+
+        Spans: ``engine.decode`` (the whole call, valued in new pairs)
+        over ``engine.decode_scan`` (the ``np.nonzero`` scan, valued in
+        cells set)."""
+        with telemetry.span("engine.decode") as sp:
+            with telemetry.span("engine.decode_scan") as scan:
+                qs, xs, vs = np.nonzero(arr)
+                scan.value = len(qs)
+            added = 0
+            for q, x, v in zip(qs.tolist(), xs.tolist(), vs.tolist()):
+                if self._simple[q] and x == v:
+                    continue
+                xv = vertex_of[x]
+                vv = vertex_of[v]
+                if xv is None or vv is None:
+                    continue
+                p = (xv, vv)
+                if p not in self.per_query_results[q]:
+                    self.per_query_results[q].add(p)
+                    self.per_query_log[q].append((t, p))
+                    fresh[q].add(p)
+                    added += 1
+            sp.value = added
 
     def current_results(self, qi: int = 0) -> Set[Pair]:
         """Snapshot view (explicit-window semantics) for lane `qi`."""

@@ -36,12 +36,14 @@ hot path never blocks on a host sync.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from .backend import BackendLike, ContractionBackend, resolve_backend
 from .semiring import (
     NEG_INF,
@@ -145,12 +147,13 @@ def apply_batch(arrays: BatchedEngineArrays, src, dst, lab, ts, mask,
     EllAdjacency is a different pytree, so each layout owns its compile
     cache entry): ELL scatters into row slots with in-dispatch spill on
     per-row overflow — same max-fold, same clock."""
-    eff_ts = jnp.where(mask, ts, NEG_INF)
-    if isinstance(arrays.adj, EllAdjacency):
-        adj = ell_insert(arrays.adj, src, dst, lab, eff_ts, mask)
-    else:
-        adj = arrays.adj.at[lab, src, dst].max(eff_ts, mode="drop")
-    now = jnp.maximum(arrays.now, jnp.maximum(jnp.max(eff_ts), ts_floor))
+    with jax.named_scope("apply_batch"):
+        eff_ts = jnp.where(mask, ts, NEG_INF)
+        if isinstance(arrays.adj, EllAdjacency):
+            adj = ell_insert(arrays.adj, src, dst, lab, eff_ts, mask)
+        else:
+            adj = arrays.adj.at[lab, src, dst].max(eff_ts, mode="drop")
+        now = jnp.maximum(arrays.now, jnp.maximum(jnp.max(eff_ts), ts_floor))
     return adj, now
 
 
@@ -158,11 +161,12 @@ def drop_batch(arrays: BatchedEngineArrays, src, dst, lab, mask):
     """The delete dispatch prologue on both layouts: clear the masked
     batch's adjacency entries (every stored copy for ELL — row slots AND
     ring). Returns the retained adjacency."""
-    if isinstance(arrays.adj, EllAdjacency):
-        return ell_delete(arrays.adj, src, dst, lab, mask)
-    drop = jnp.where(mask, jnp.asarray(NEG_INF, jnp.float32),
-                     arrays.adj[lab, src, dst])
-    return arrays.adj.at[lab, src, dst].set(drop, mode="drop")
+    with jax.named_scope("drop_batch"):
+        if isinstance(arrays.adj, EllAdjacency):
+            return ell_delete(arrays.adj, src, dst, lab, mask)
+        drop = jnp.where(mask, jnp.asarray(NEG_INF, jnp.float32),
+                         arrays.adj[lab, src, dst])
+        return arrays.adj.at[lab, src, dst].set(drop, mode="drop")
 
 
 def emit_new(arrays: BatchedEngineArrays, dist, adj, now, finals_mask,
@@ -170,10 +174,11 @@ def emit_new(arrays: BatchedEngineArrays, dist, adj, now, finals_mask,
     """The ingest dispatch epilogue, shared likewise: per-query window
     validity at the new clock, diffed against the emitted frontier.
     Returns ``(new_arrays, new)``."""
-    low = now - windows
-    valid = batched_valid_pairs(dist, finals_mask, low)
-    new = jnp.logical_and(valid, jnp.logical_not(arrays.emitted))
-    emitted = jnp.logical_or(arrays.emitted, valid)
+    with jax.named_scope("emit_new"):
+        low = now - windows
+        valid = batched_valid_pairs(dist, finals_mask, low)
+        new = jnp.logical_and(valid, jnp.logical_not(arrays.emitted))
+        emitted = jnp.logical_or(arrays.emitted, valid)
     return BatchedEngineArrays(adj, dist, emitted, now), new
 
 
@@ -440,10 +445,12 @@ class Executor:
         self.steps = 0  # jitted ingest/delete dispatches
         self._arrays: Optional[BatchedEngineArrays] = None
         # (rounds_dev, qrounds_dev, n_live, fstats_dev|None, n_slots,
-        # is_delete) queue: converted lazily so the per-dispatch hot path
-        # never blocks on a device->host sync
+        # is_delete, (q_cap, f_cap, dispatch perf_counter_ns)) queue:
+        # converted lazily so the per-dispatch hot path never blocks on a
+        # device->host sync
         self._pending_counts: List[
-            Tuple[object, object, int, object, int, bool]] = []
+            Tuple[object, object, int, object, int, bool,
+                  Tuple[int, int, int]]] = []
         self._rounds_total = 0
         self._query_rounds_total = 0
         self._unmasked_query_rounds_total = 0
@@ -636,13 +643,20 @@ class Executor:
         one: per-event work scales with the rows the batch dirties, not N
         (overflow falls back to the dense loop in-dispatch; results are
         bit-identical either way)."""
-        if self.adj_layout == "ell":
-            self._reserve_spill(len(src))
-        if self.dist_layout == "row_sparse":
-            self._reserve_dist(self.frontier != "off")
-        if self.frontier != "off":
-            return self._ingest_frontier_dispatch(
+        with telemetry.span("executor.dispatch", len(src)):
+            with telemetry.span("executor.reserve"):
+                if self.adj_layout == "ell":
+                    self._reserve_spill(len(src))
+                if self.dist_layout == "row_sparse":
+                    self._reserve_dist(self.frontier != "off")
+            if self.frontier != "off":
+                return self._ingest_frontier_dispatch(
+                    src, dst, lab, ts, mask, ts_floor, tables)
+            return self._ingest_dispatch(
                 src, dst, lab, ts, mask, ts_floor, tables)
+
+    def _ingest_dispatch(self, src, dst, lab, ts, mask, ts_floor: float,
+                         tables: QueryTables):
         self._arrays, new, rounds, qrounds = _ingest(
             self._arrays,
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
@@ -681,11 +695,17 @@ class Executor:
         dropped edges are cleared and re-derived (overflow falls back to
         the dense from-scratch loop in-dispatch; results are bit-identical
         either way)."""
-        if self.dist_layout == "row_sparse":
-            self._reserve_dist(self.frontier != "off")
-        if self.frontier != "off":
-            return self._delete_frontier_dispatch(
-                src, dst, lab, mask, ts_now, tables)
+        with telemetry.span("executor.dispatch", len(src)):
+            with telemetry.span("executor.reserve"):
+                if self.dist_layout == "row_sparse":
+                    self._reserve_dist(self.frontier != "off")
+            if self.frontier != "off":
+                return self._delete_frontier_dispatch(
+                    src, dst, lab, mask, ts_now, tables)
+            return self._delete_dispatch(src, dst, lab, mask, ts_now, tables)
+
+    def _delete_dispatch(self, src, dst, lab, mask, ts_now: float,
+                         tables: QueryTables):
         self._arrays, invalidated, rounds, qrounds = _delete(
             self._arrays,
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
@@ -741,7 +761,8 @@ class Executor:
             self._arrays, jnp.asarray(tau, jnp.float32),
             jnp.asarray(max_window, jnp.float32),
         )
-        return np.asarray(live)
+        with telemetry.span("executor.sync.expire_live"):
+            return np.asarray(live)
 
     def clear_slots(self, slots: Sequence[int]) -> None:
         self._arrays = _clear_slots(
@@ -795,9 +816,11 @@ class Executor:
 
     def _drain_spill(self) -> None:
         self._ell_spill_drains += 1
-        ptr = int(jax.device_get(self._arrays.adj.spill_ptr))
+        with telemetry.span("executor.sync.drain_spill"):
+            ptr = int(jax.device_get(self._arrays.adj.spill_ptr))
+            need = (int(jax.device_get(ell_max_degree(self._arrays.adj)))
+                    if ptr > 0 else 0)
         if ptr > 0:
-            need = int(jax.device_get(ell_max_degree(self._arrays.adj)))
             while self.ell_cap < need:
                 self.ell_cap *= 2
             self._repack_ell()
@@ -874,10 +897,12 @@ class Executor:
     def _drain_dist(self) -> None:
         self._dist_drains += 1
         d = self._arrays.dist
-        ptr, lost = (int(x) for x in jax.device_get((d.ovf_ptr, d.lost)))
+        with telemetry.span("executor.sync.drain_dist"):
+            ptr, lost = (int(x) for x in jax.device_get((d.ovf_ptr, d.lost)))
+            need = (int(jax.device_get(jnp.max(rsd_row_counts(d))))
+                    if ptr > 0 else 0)
         self._dist_lost = lost
         if ptr > 0:
-            need = int(jax.device_get(jnp.max(rsd_row_counts(d))))
             while self.dist_cap < need:
                 self.dist_cap *= 2
             self._repack_dist()
@@ -932,9 +957,10 @@ class Executor:
 
     def _account(self, rounds, qrounds, n_live: int, fstats=None,
                  is_delete: bool = False) -> None:
-        n = self.dist_shape[1] if self._arrays is not None else 0
+        q, n = self.dist_shape[:2] if self._arrays is not None else (0, 0)
         self._pending_counts.append(
-            (rounds, qrounds, n_live, fstats, n, is_delete))
+            (rounds, qrounds, n_live, fstats, n, is_delete,
+             (q, self.frontier_cap, time.perf_counter_ns())))
         # auto-frontier flushes more eagerly: the ×2 capacity growth reads
         # the flushed overflow telemetry, and reacting a couple hundred
         # dispatches late would strand the stream on the dense fallback
@@ -943,11 +969,15 @@ class Executor:
             self._flush_counts()
 
     def _flush_counts(self) -> None:
-        for rounds, qrounds, n_live, fstats, n, is_delete in \
-                self._pending_counts:
-            self._consume_count(rounds, qrounds, n_live)
-            self._consume_frontier(fstats, rounds, n_live, n, is_delete)
-        self._pending_counts.clear()
+        if self._pending_counts:
+            with telemetry.span("executor.sync.flush_counts",
+                                len(self._pending_counts)):
+                for (rounds, qrounds, n_live, fstats, n, is_delete,
+                     slab) in self._pending_counts:
+                    self._consume_count(rounds, qrounds, n_live)
+                    self._consume_frontier(fstats, rounds, n_live, n,
+                                           is_delete, slab)
+            self._pending_counts.clear()
         self._maybe_grow_frontier()
 
     def _consume_count(self, rounds, qrounds, n_live: int) -> None:
@@ -957,11 +987,30 @@ class Executor:
         self._unmasked_query_rounds_total += n_live * r
 
     def _consume_frontier(self, fstats, rounds, n_live: int, n: int,
-                          is_delete: bool = False) -> None:
+                          is_delete: bool = False,
+                          slab: Tuple[int, int, int] = (0, 0, 0)) -> None:
         """Aggregate one dispatch's FrontierStats. Works on scalar stats
-        (local) and per-shard arrays (mesh) alike: sums/maxes reduce both."""
+        (local) and per-shard arrays (mesh) alike: sums/maxes reduce both.
+
+        Also counts, stamped with the dispatch's time, the rows of the
+        ``(q_cap, f_cap)`` slab its rounds carried
+        (``frontier.slab_rows``: q_cap · f_cap · rounds) and the rows
+        they relaxed (``frontier.rows_relaxed``), over the shards that
+        took the frontier branch (a dense fallback carries no slab)."""
         if fstats is None:
             return
+        q_cap, f_cap, t_ns = slab
+        r_sh, fb_sh, rr_sh = np.broadcast_arrays(
+            np.asarray(rounds).astype(np.int64).reshape(-1),
+            np.asarray(fstats.fell_back).reshape(-1),
+            np.asarray(fstats.rows_relaxed).astype(np.int64).reshape(-1))
+        kept = ~fb_sh
+        if kept.any():
+            telemetry.count("frontier.slab_rows",
+                            q_cap // r_sh.size * f_cap
+                            * int(r_sh[kept].sum()), t_ns)
+            telemetry.count("frontier.rows_relaxed",
+                            int(rr_sh[kept].sum()), t_ns)
         self._frontier_dispatches += 1
         fell = int(np.asarray(fstats.fell_back).astype(np.int64).sum())
         self._frontier_fallbacks += fell

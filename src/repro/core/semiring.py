@@ -399,11 +399,12 @@ def batched_valid_pairs(
     the sparse emit (:func:`~repro.core.sparse_dist.rsd_valid_pairs`):
     only stored entries are reduced — O(Q·N·C) instead of the dense
     O(Q·N²·K) scan that dominates per-event cost at large N."""
-    if isinstance(dist, RowSparseDist):
-        return rsd_valid_pairs(dist, finals, low)
-    acc = jnp.where(finals[:, None, None, :], dist, NEG_INF)
-    best = jnp.max(acc, axis=3)
-    return best > low[:, None, None]
+    with jax.named_scope("batched_valid_pairs"):
+        if isinstance(dist, RowSparseDist):
+            return rsd_valid_pairs(dist, finals, low)
+        acc = jnp.where(finals[:, None, None, :], dist, NEG_INF)
+        best = jnp.max(acc, axis=3)
+        return best > low[:, None, None]
 
 
 # ---------------------------------------------------------------------------
@@ -457,15 +458,16 @@ def frontier_seed(
     finite timestamp encodes to the bucket zero; relaxing its row is then a
     no-op, never an error)."""
     q, n, _, k = dist.shape
-    idx = jnp.where(smask, src, n)     # out-of-range -> dropped
-    src_mask = jnp.zeros((n,), bool).at[idx].set(True, mode="drop")
-    reach = jnp.any(
-        jnp.logical_and(dist > NEG_INF, src_mask[None, None, :, None]),
-        axis=(2, 3),
-    )                                   # (Q, N) rows reaching a batch source
-    dirty = jnp.logical_or(reach, src_mask[None, :])
-    if query_mask is not None:
-        dirty = jnp.logical_and(dirty, query_mask[:, None])
+    with jax.named_scope("frontier_seed"):
+        idx = jnp.where(smask, src, n)     # out-of-range -> dropped
+        src_mask = jnp.zeros((n,), bool).at[idx].set(True, mode="drop")
+        reach = jnp.any(
+            jnp.logical_and(dist > NEG_INF, src_mask[None, None, :, None]),
+            axis=(2, 3),
+        )                                   # (Q, N) rows reaching a batch source
+        dirty = jnp.logical_or(reach, src_mask[None, :])
+        if query_mask is not None:
+            dirty = jnp.logical_and(dirty, query_mask[:, None])
     return dirty
 
 
@@ -489,16 +491,17 @@ def frontier_seed_gathered(
     O(N²) wall); the dense layout keeps the scan so its dispatch shapes
     and telemetry stay byte-stable."""
     q, n, _, k = dist.shape
-    cols = dist[:, :, jnp.where(smask, src, 0), :]       # (Q, N, B, K)
-    reach = jnp.any(
-        jnp.logical_and(cols > NEG_INF, smask[None, None, :, None]),
-        axis=(2, 3),
-    )                                   # (Q, N) rows reaching a batch source
-    idx = jnp.where(smask, src, n)
-    src_mask = jnp.zeros((n,), bool).at[idx].set(True, mode="drop")
-    dirty = jnp.logical_or(reach, src_mask[None, :])
-    if query_mask is not None:
-        dirty = jnp.logical_and(dirty, query_mask[:, None])
+    with jax.named_scope("frontier_seed"):
+        cols = dist[:, :, jnp.where(smask, src, 0), :]       # (Q, N, B, K)
+        reach = jnp.any(
+            jnp.logical_and(cols > NEG_INF, smask[None, None, :, None]),
+            axis=(2, 3),
+        )                                   # (Q, N) rows reaching a batch source
+        idx = jnp.where(smask, src, n)
+        src_mask = jnp.zeros((n,), bool).at[idx].set(True, mode="drop")
+        dirty = jnp.logical_or(reach, src_mask[None, :])
+        if query_mask is not None:
+            dirty = jnp.logical_and(dirty, query_mask[:, None])
     return dirty
 
 
@@ -515,14 +518,15 @@ def pack_frontier(
     rows are dropped here, which is why callers must take the dense
     fallback in that case)."""
     q, n = dirty.shape
-    cnt = jnp.sum(dirty, axis=1).astype(jnp.int32)
-    pos = jnp.cumsum(dirty, axis=1) - 1                  # (Q, N)
-    pos = jnp.where(dirty, jnp.minimum(pos, f_cap), f_cap)
-    rows = jnp.zeros((q, f_cap), jnp.int32).at[
-        jnp.arange(q)[:, None], pos
-    ].set(jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (q, n)),
-          mode="drop")
-    rowmask = jnp.arange(f_cap)[None, :] < jnp.minimum(cnt, f_cap)[:, None]
+    with jax.named_scope("pack_frontier"):
+        cnt = jnp.sum(dirty, axis=1).astype(jnp.int32)
+        pos = jnp.cumsum(dirty, axis=1) - 1                  # (Q, N)
+        pos = jnp.where(dirty, jnp.minimum(pos, f_cap), f_cap)
+        rows = jnp.zeros((q, f_cap), jnp.int32).at[
+            jnp.arange(q)[:, None], pos
+        ].set(jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :],
+                               (q, n)), mode="drop")
+        rowmask = jnp.arange(f_cap)[None, :] < jnp.minimum(cnt, f_cap)[:, None]
     return rows, rowmask, cnt
 
 
@@ -653,6 +657,7 @@ def frontier_closure(
     overflow = jnp.any(cnt > f_cap)
     dist_op, adj_op = backend.prepare_state(dist, adj, now, w_max)
 
+    @jax.named_scope("dense_fallback")
     def dense_branch(_):
         d_f, rounds, qrounds = _masked_closure_loop(
             dist_op, adj_op, btt, backend, mask0, bound)
@@ -664,6 +669,7 @@ def frontier_closure(
             _d, rm, it, _qr, _rr = carry
             return jnp.logical_and(jnp.any(rm), it < bound)
 
+        @jax.named_scope("frontier_round")
         def body(carry):
             d, rm, it, qr, rr = carry
             nd, changed = frontier_relax_round(d, adj_op, btt, backend,
@@ -785,6 +791,7 @@ def frontier_delete(
     cleared = jnp.where(dirty[:, :, None, None], NEG_INF, dist)
     dist_op, adj_op = backend.prepare_state(cleared, adj, now, w_max)
 
+    @jax.named_scope("dense_fallback")
     def dense_branch(_):
         # from-scratch over ALL rows — exactly what the non-frontier delete
         # dispatch runs, so a fallback stays bit-identical to frontier="off"
@@ -799,6 +806,7 @@ def frontier_delete(
             _d, rm, it, _qr, _rr = carry
             return jnp.logical_and(jnp.any(rm), it < bound)
 
+        @jax.named_scope("frontier_round")
         def body(carry):
             d, rm, it, qr, rr = carry
             nd, changed = frontier_relax_round(d, adj_op, btt, backend,
@@ -882,6 +890,7 @@ def _rowsparse_frontier_closure(
     _, adj_op = backend.prepare_state(
         jnp.asarray(NEG_INF, jnp.float32), adj, now, w_max)
 
+    @jax.named_scope("dense_fallback")
     def dense_branch(_):
         d_op = backend.encode(rsd_to_dense(sd), now, w_max)
         d_f, rounds, qrounds = _masked_closure_loop(
@@ -892,13 +901,15 @@ def _rowsparse_frontier_closure(
         return out, rounds, qrounds, rounds * live_rows
 
     def frontier_branch(_):
-        slab0 = rsd_gather_rows(sd, rows, backend.gather_dist_rows)
-        slab_op = backend.encode(slab0, now, w_max)
+        with jax.named_scope("frontier_gather"):
+            slab0 = rsd_gather_rows(sd, rows, backend.gather_dist_rows)
+            slab_op = backend.encode(slab0, now, w_max)
 
         def cond(carry):
             _s, rm, it, _qr, _rr = carry
             return jnp.logical_and(jnp.any(rm), it < bound)
 
+        @jax.named_scope("frontier_round")
         def body(carry):
             s, rm, it, qr, rr = carry
             ns, changed = _frontier_slab_round(s, adj_op, btt, backend,
@@ -911,8 +922,9 @@ def _rowsparse_frontier_closure(
             cond, body,
             (slab_op, rowmask0, jnp.asarray(0, jnp.int32),
              jnp.zeros((q,), jnp.int32), jnp.asarray(0, jnp.int32)))
-        slab_f = backend.decode_state(s_f, now, w_max)
-        out = rsd_scatter_rows(sd, rows, rowmask0, slab_f)
+        with jax.named_scope("frontier_scatter"):
+            slab_f = backend.decode_state(s_f, now, w_max)
+            out = rsd_scatter_rows(sd, rows, rowmask0, slab_f)
         return out, rounds, qrounds, rr
 
     out, rounds, qrounds, rows_relaxed = jax.lax.cond(
@@ -953,6 +965,7 @@ def _rowsparse_frontier_delete(
     _, adj_op = backend.prepare_state(
         jnp.asarray(NEG_INF, jnp.float32), adj, now, w_max)
 
+    @jax.named_scope("dense_fallback")
     def dense_branch(_):
         # from-scratch over ALL rows — exactly the non-frontier delete
         # computation, re-packed in-jit on the way out
@@ -975,6 +988,7 @@ def _rowsparse_frontier_delete(
             _s, rm, it, _qr, _rr = carry
             return jnp.logical_and(jnp.any(rm), it < bound)
 
+        @jax.named_scope("frontier_round")
         def body(carry):
             s, rm, it, qr, rr = carry
             ns, changed = _frontier_slab_round(s, adj_op, btt, backend,
@@ -987,8 +1001,9 @@ def _rowsparse_frontier_delete(
             cond, body,
             (slab0, rowmask0, jnp.asarray(0, jnp.int32),
              jnp.zeros((q,), jnp.int32), jnp.asarray(0, jnp.int32)))
-        slab_f = backend.decode_state(s_f, now, w_max)
-        out = rsd_scatter_rows(sd, rows, rowmask0, slab_f)
+        with jax.named_scope("frontier_scatter"):
+            slab_f = backend.decode_state(s_f, now, w_max)
+            out = rsd_scatter_rows(sd, rows, rowmask0, slab_f)
         return out, rounds, qrounds, rr
 
     out, rounds, qrounds, rows_relaxed = jax.lax.cond(

@@ -309,19 +309,20 @@ def rsd_seed_gathered(sd: RowSparseDist, src: jax.Array, smask: jax.Array,
     q, n, _c = sd.idx.shape
     e = sd.ovf_ts.shape[1]
     k = e // n
-    idx_b = jnp.where(smask, src, n)
-    src_mask = jnp.zeros((n,), bool).at[idx_b].set(True, mode="drop")
-    hit = (sd.ts > NEG_INF) & src_mask[sd.idx // k]
-    reach = jnp.any(hit, axis=-1).astype(jnp.int32)        # (Q, N)
-    live = sd.ovf_rows >= 0
-    row = jnp.where(live, sd.ovf_rows, 0)
-    ovf = sd.ovf_ts.reshape(-1, n, k)
-    hit_r = jnp.any((ovf > NEG_INF) & src_mask[None, :, None],
-                    axis=(1, 2)) & live
-    reach = reach.at[row // n, row % n].max(hit_r.astype(jnp.int32))
-    dirty = (reach > 0) | src_mask[None, :]
-    if query_mask is not None:
-        dirty = dirty & query_mask[:, None]
+    with jax.named_scope("frontier_seed"):
+        idx_b = jnp.where(smask, src, n)
+        src_mask = jnp.zeros((n,), bool).at[idx_b].set(True, mode="drop")
+        hit = (sd.ts > NEG_INF) & src_mask[sd.idx // k]
+        reach = jnp.any(hit, axis=-1).astype(jnp.int32)        # (Q, N)
+        live = sd.ovf_rows >= 0
+        row = jnp.where(live, sd.ovf_rows, 0)
+        ovf = sd.ovf_ts.reshape(-1, n, k)
+        hit_r = jnp.any((ovf > NEG_INF) & src_mask[None, :, None],
+                        axis=(1, 2)) & live
+        reach = reach.at[row // n, row % n].max(hit_r.astype(jnp.int32))
+        dirty = (reach > 0) | src_mask[None, :]
+        if query_mask is not None:
+            dirty = dirty & query_mask[:, None]
     return dirty
 
 

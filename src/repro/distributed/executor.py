@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..core.executor import (
     BatchedEngineArrays,
     Executor,
@@ -623,18 +624,32 @@ class MeshExecutor(Executor):
 
     def ingest_batch(self, src, dst, lab, ts, mask, ts_floor: float,
                      tables: QueryTables):
-        q_cap = self.dist_shape[0]
-        rows = self._rows_for(tables.btt, q_cap)
-        if self.adj_layout == "ell":
-            self._reserve_spill(len(src))
-        if self.dist_layout == "row_sparse":
-            self._reserve_dist(self.frontier != "off")
-        if self.frontier != "off":
-            ingest = _mesh_frontier_ingest(
-                self.mesh, self.q_axes, self.backend, self.frontier_cap,
-                self.adj_layout, self.dist_layout)
-            (self._arrays, new, shard_rounds, qrounds,
-             rr, fb, seed, mx) = ingest(
+        with telemetry.span("executor.dispatch", len(src)):
+            q_cap = self.dist_shape[0]
+            rows = self._rows_for(tables.btt, q_cap)
+            with telemetry.span("executor.reserve"):
+                if self.adj_layout == "ell":
+                    self._reserve_spill(len(src))
+                if self.dist_layout == "row_sparse":
+                    self._reserve_dist(self.frontier != "off")
+            if self.frontier != "off":
+                ingest = _mesh_frontier_ingest(
+                    self.mesh, self.q_axes, self.backend, self.frontier_cap,
+                    self.adj_layout, self.dist_layout)
+                (self._arrays, new, shard_rounds, qrounds,
+                 rr, fb, seed, mx) = ingest(
+                    self._arrays,
+                    jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
+                    jnp.asarray(ts), jnp.asarray(mask),
+                    jnp.asarray(ts_floor, jnp.float32),
+                    rows, tables.finals_mask, tables.windows, tables.live_mask,
+                    jnp.asarray(tables.max_window, jnp.float32),
+                )
+                self._account(shard_rounds, qrounds, tables.n_live,
+                              FrontierStats(seed, mx, rr, fb))
+                self.steps += 1
+                return new
+            self._arrays, new, shard_rounds, qrounds = self._jit_ingest(
                 self._arrays,
                 jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
                 jnp.asarray(ts), jnp.asarray(mask),
@@ -642,54 +657,44 @@ class MeshExecutor(Executor):
                 rows, tables.finals_mask, tables.windows, tables.live_mask,
                 jnp.asarray(tables.max_window, jnp.float32),
             )
-            self._account(shard_rounds, qrounds, tables.n_live,
-                          FrontierStats(seed, mx, rr, fb))
+            self._account(shard_rounds, qrounds, tables.n_live)
             self.steps += 1
             return new
-        self._arrays, new, shard_rounds, qrounds = self._jit_ingest(
-            self._arrays,
-            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
-            jnp.asarray(ts), jnp.asarray(mask),
-            jnp.asarray(ts_floor, jnp.float32),
-            rows, tables.finals_mask, tables.windows, tables.live_mask,
-            jnp.asarray(tables.max_window, jnp.float32),
-        )
-        self._account(shard_rounds, qrounds, tables.n_live)
-        self.steps += 1
-        return new
 
     def delete_batch(self, src, dst, lab, mask, ts_now: float,
                      tables: QueryTables):
-        q_cap = self.dist_shape[0]
-        rows = self._rows_for(tables.btt, q_cap)
-        if self.dist_layout == "row_sparse":
-            self._reserve_dist(self.frontier != "off")
-        if self.frontier != "off":
-            delete = _mesh_frontier_delete(
-                self.mesh, self.q_axes, self.backend, self.frontier_cap,
-                self.adj_layout, self.dist_layout)
-            (self._arrays, invalidated, shard_rounds, qrounds,
-             rr, fb, seed, mx) = delete(
+        with telemetry.span("executor.dispatch", len(src)):
+            q_cap = self.dist_shape[0]
+            rows = self._rows_for(tables.btt, q_cap)
+            with telemetry.span("executor.reserve"):
+                if self.dist_layout == "row_sparse":
+                    self._reserve_dist(self.frontier != "off")
+            if self.frontier != "off":
+                delete = _mesh_frontier_delete(
+                    self.mesh, self.q_axes, self.backend, self.frontier_cap,
+                    self.adj_layout, self.dist_layout)
+                (self._arrays, invalidated, shard_rounds, qrounds,
+                 rr, fb, seed, mx) = delete(
+                    self._arrays,
+                    jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
+                    jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
+                    rows, tables.finals_mask, tables.windows, tables.live_mask,
+                    jnp.asarray(tables.max_window, jnp.float32),
+                )
+                self._account(shard_rounds, qrounds, tables.n_live,
+                              FrontierStats(seed, mx, rr, fb), is_delete=True)
+                self.steps += 1
+                return invalidated
+            self._arrays, invalidated, shard_rounds, qrounds = self._jit_delete(
                 self._arrays,
                 jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
                 jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
                 rows, tables.finals_mask, tables.windows, tables.live_mask,
                 jnp.asarray(tables.max_window, jnp.float32),
             )
-            self._account(shard_rounds, qrounds, tables.n_live,
-                          FrontierStats(seed, mx, rr, fb), is_delete=True)
+            self._account(shard_rounds, qrounds, tables.n_live)
             self.steps += 1
             return invalidated
-        self._arrays, invalidated, shard_rounds, qrounds = self._jit_delete(
-            self._arrays,
-            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
-            jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
-            rows, tables.finals_mask, tables.windows, tables.live_mask,
-            jnp.asarray(tables.max_window, jnp.float32),
-        )
-        self._account(shard_rounds, qrounds, tables.n_live)
-        self.steps += 1
-        return invalidated
 
     def relax(self, tables: QueryTables,
               query_mask: Optional[np.ndarray] = None) -> None:

@@ -8,8 +8,8 @@ Mechanisms (all exercised by tests on CPU; deployment notes in DESIGN.md §4):
   scheduler would supervise. Failures mid-save can never corrupt state
   (atomic manifest+LATEST protocol in checkpoint/ckpt.py).
 
-* **straggler mitigation** — `StragglerMonitor` tracks per-step wall times;
-  a step exceeding `deadline_factor` x the trailing median is recorded and
+* **straggler mitigation** — `StragglerMonitor` keeps the last 32 step
+  times; a step exceeding `deadline_factor` x their median is recorded and
   (on real clusters) would trigger the backup-task path; here the policy
   hook `on_straggler` lets the driver skip a slow data shard (the pipeline
   is deterministic per (host, step), so skipping is reproducible).
@@ -21,25 +21,29 @@ Mechanisms (all exercised by tests on CPU; deployment notes in DESIGN.md §4):
 """
 from __future__ import annotations
 
+import collections
 import time
 from statistics import median
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from ..checkpoint import ckpt
 
 
 class StragglerMonitor:
+    #: step times the deadline's median is taken over (the newest ones)
+    WINDOW = 32
+
     def __init__(self, deadline_factor: float = 3.0, warmup: int = 5):
         self.deadline_factor = deadline_factor
         self.warmup = warmup
-        self.times: List[float] = []
+        self.times: Deque[float] = collections.deque(maxlen=self.WINDOW)
         self.stragglers: List[int] = []
 
     def observe(self, step: int, dt: float) -> bool:
         """Record a step time; returns True if this step was a straggler."""
         is_straggler = False
         if len(self.times) >= self.warmup:
-            med = median(self.times[-32:])
+            med = median(self.times)
             if dt > self.deadline_factor * med:
                 self.stragglers.append(step)
                 is_straggler = True

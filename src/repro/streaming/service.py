@@ -27,7 +27,7 @@ blocking the hot path (engine :class:`~repro.core.engine.PendingResults`;
 decode safety is preserved by per-dispatch interner snapshots and strict
 FIFO drain order, and all handles resolve before any expiry, deletion,
 lifecycle event, or the end of :meth:`ingest`, so the returned report is
-complete). Recorded latencies then measure dispatch time only.
+complete).
 
 Adaptive micro-batching (PR 4, opt-in ``adaptive_batch=True``): dense
 inserts buffer into micro-batches whose size doubles/halves (power-of-two
@@ -92,9 +92,11 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Deque, Dict, List, Optional, Set, Tuple, Union
 
+import jax
+
+from .. import telemetry
 from ..core.automaton import compile_query
 from ..core.backend import resolve_backend
 from ..core.engine import BatchedDenseRPQEngine, PendingResults, RegisteredQuery
@@ -114,9 +116,6 @@ class QueryStats:
     tuples: int = 0
     results: int = 0
     conflicted: bool = False
-    wall_s: float = 0.0
-    p99_us: float = 0.0
-    latencies_us: Optional[List[float]] = None
 
 
 class IngestReport(Dict[str, Set[Tuple]]):
@@ -457,7 +456,7 @@ class PersistentQueryService:
         else:
             self._ref_engines[name] = RAPQ(dfa, self.window)
         if name not in self.stats:  # a reused name keeps its history
-            self.stats[name] = QueryStats(latencies_us=[])
+            self.stats[name] = QueryStats()
         return initial
 
     def deregister(self, name: str) -> None:
@@ -520,7 +519,7 @@ class PersistentQueryService:
             if name in self.stats:
                 self.stats[name].conflicted = True
 
-    def ingest(self, stream, record_latency: bool = False) -> IngestReport:
+    def ingest(self, stream) -> IngestReport:
         """Feed the whole stream; returns an :class:`IngestReport`: the new
         result pairs per query (dict interface), with the pairs invalidated
         by explicit deletions alongside in ``.invalidated`` and any
@@ -540,200 +539,185 @@ class PersistentQueryService:
         :attr:`batch_size_log`; B > 1 carries the engine's documented
         batch-boundary skew, which is why this is never on by default.
         """
-        self._ensure_group()
-        self._ingest_started = True
-        new_results: Dict[str, Set[Tuple]] = {name: set() for name in self.stats}
-        invalidated: Dict[str, Set[Tuple]] = {name: set() for name in self.stats}
-        fallbacks: Dict[str, str] = {}
-        # reading frontier_stats flushes the executor's queued counters —
-        # but the PREVIOUS call's end-of-ingest read already drained them,
-        # so this start-of-call snapshot is amortized-free (it only pays
-        # when the engine was driven directly between service calls); the
-        # per-call cost is bounded by flushing this call's own dispatches,
-        # which reporting per-call stats requires anyway
-        call_mark: Dict[str, object] = (
-            dict(self._group.executor.frontier_stats)
-            if self._group is not None and self._frontier != "off" else {})
-        # bounded FIFO (async_depth) — deque so the drain below is O(1)
-        # per handle instead of list.pop(0)'s O(n) shift
-        pending: Deque[PendingResults] = collections.deque()
-        dense_buf: List = []               # adaptive micro-batch buffer
-        del_buf: List = []                 # negative-tuple micro-batch buffer
-        deletions = [0]                    # negative tuples seen by the group
+        with telemetry.span("service.ingest") as span:
+            self._ensure_group()
+            self._ingest_started = True
+            new_results: Dict[str, Set[Tuple]] = {name: set() for name in self.stats}
+            invalidated: Dict[str, Set[Tuple]] = {name: set() for name in self.stats}
+            fallbacks: Dict[str, str] = {}
+            # reading frontier_stats flushes the executor's queued counters —
+            # but the PREVIOUS call's end-of-ingest read already drained them,
+            # so this start-of-call snapshot is amortized-free (it only pays
+            # when the engine was driven directly between service calls); the
+            # per-call cost is bounded by flushing this call's own dispatches,
+            # which reporting per-call stats requires anyway
+            call_mark: Dict[str, object] = (
+                dict(self._group.executor.frontier_stats)
+                if self._group is not None and self._frontier != "off" else {})
+            # bounded FIFO (async_depth) — deque so the drain below is O(1)
+            # per handle instead of list.pop(0)'s O(n) shift
+            pending: Deque[PendingResults] = collections.deque()
+            dense_buf: List = []               # adaptive micro-batch buffer
+            del_buf: List = []                 # negative-tuple micro-batch buffer
+            deletions = [0]                    # negative tuples seen by the group
 
-        def resolve_pending(limit: int = 0) -> None:
-            """Resolve outstanding decode handles down to `limit` (dispatch
-            order; each handle snapshotted the interner at dispatch)."""
-            while len(pending) > limit:
-                fresh = pending.popleft().resolve()
+            def resolve_pending(limit: int = 0) -> None:
+                """Resolve outstanding decode handles down to `limit` (dispatch
+                order; each handle snapshotted the interner at dispatch)."""
+                while len(pending) > limit:
+                    fresh = pending.popleft().resolve()
+                    for qi, spec in self._group.live_items():
+                        new_results[spec.name] |= fresh[qi]
+
+            def flush_dense() -> None:
+                """Dispatch the buffered dense inserts as one micro-batch."""
+                if not dense_buf:
+                    return
+                batch = [(s.src, s.dst, s.label, s.ts) for s in dense_buf]
+                handle = self._group.insert_batch_pending(batch)
+                pending.append(handle)
+                # pull results down to the in-flight budget: depth k means the
+                # device->host transfer of dispatch i overlaps dispatches
+                # i+1..i+k instead of blocking the hot path
+                resolve_pending(self._async_depth if self._async_decode else 0)
                 for qi, spec in self._group.live_items():
-                    new_results[spec.name] |= fresh[qi]
+                    self.stats[spec.name].tuples += len(batch)
+                dense_buf.clear()
+                self._maybe_fallback(fallbacks, lambda: resolve_pending(0))
 
-        def flush_dense() -> None:
-            """Dispatch the buffered dense inserts as one micro-batch."""
-            if not dense_buf:
-                return
-            batch = [(s.src, s.dst, s.label, s.ts) for s in dense_buf]
-            t0 = time.perf_counter_ns() if record_latency else 0
-            handle = self._group.insert_batch_pending(batch)
-            pending.append(handle)
-            # pull results down to the in-flight budget: depth k means the
-            # device->host transfer of dispatch i overlaps dispatches
-            # i+1..i+k instead of blocking the hot path
-            resolve_pending(self._async_depth if self._async_decode else 0)
-            dt = (time.perf_counter_ns() - t0) / 1e3 if record_latency else 0.0
-            for qi, spec in self._group.live_items():
-                st = self.stats[spec.name]
-                st.tuples += len(batch)
-                if record_latency:
-                    # one dispatch serves the whole group; each member
-                    # observes the group's step latency (dispatch-only
-                    # under async_decode), amortized over the micro-batch
-                    st.latencies_us.extend([dt / len(batch)] * len(batch))
-            dense_buf.clear()
-            self._maybe_fallback(fallbacks, lambda: resolve_pending(0))
-
-        def flush_deletes() -> None:
-            """Dispatch the buffered negative tuples as one micro-batch
-            through the engine's chunked delete path (frontier cone per
-            chunk when the frontier is on). Only one of dense_buf/del_buf
-            is ever non-empty — the event loop flushes the other before
-            buffering — so stream order is preserved."""
-            if not del_buf:
-                return
-            resolve_pending()
-            batch = [(s.src, s.dst, s.label, s.ts) for s in del_buf]
-            t0 = time.perf_counter_ns() if record_latency else 0
-            inv = self._group.delete_batch(batch)
-            dt = (time.perf_counter_ns() - t0) / 1e3 if record_latency else 0.0
-            for qi, spec in self._group.live_items():
-                st = self.stats[spec.name]
-                st.tuples += len(batch)
-                invalidated[spec.name] |= inv[qi]
-                if record_latency:
-                    st.latencies_us.extend([dt / len(batch)] * len(batch))
-            deletions[0] += len(batch)
-            del_buf.clear()
-            self._maybe_fallback(fallbacks, lambda: resolve_pending(0))
-
-        def mark_interval() -> Dict[str, object]:
-            """Per-interval frontier telemetry: append the delta since the
-            last slide boundary to :attr:`frontier_log` and hand it to the
-            batch steering below."""
-            delta = self._frontier_delta()
-            seen = max((self.stats[s.name].tuples
-                        for _qi, s in self._group.live_items()),
-                       default=0) if self._group is not None else 0
-            if delta:
-                self.frontier_log.append((seen, delta))
-            if (self._group is not None
-                    and self._group.executor.adj_layout == "ell"):
-                self.adjacency_log.append(
-                    (seen, self._group.executor.adjacency_stats))
-            if (self._group is not None
-                    and self._group.executor.dist_layout == "row_sparse"):
-                self.dist_log.append(
-                    (seen, self._group.executor.dist_stats))
-            return delta
-
-        def adapt_batch(finterval: Dict[str, object]) -> None:
-            """Steer the dense micro-batch size from the interval's no-op
-            relaxation tail AND the frontier telemetry (see docstring)."""
-            if not self._adaptive_batch or self._group is None:
-                return
-            ex = self._group.executor
-            qr, uqr = ex.query_rounds_total, ex.unmasked_query_rounds_total
-            if self._adapt_marks is not None:
-                dqr = qr - self._adapt_marks[0]
-                duqr = uqr - self._adapt_marks[1]
-                if duqr > 0:
-                    noop_frac = 1.0 - dqr / duqr
-                    b = self._group.batch_size
-                    # the no-op tail argues for a bigger B (dispatch
-                    # overhead dominates useful work) — but when the
-                    # frontier is live and healthy (tiny row occupancy, no
-                    # overflow pressure) each dispatch is ALREADY cheap in
-                    # proportion to its dirty rows, so growing B would
-                    # trade exactness (batch-boundary skew) for little:
-                    # hold B instead
-                    frontier_healthy = self._frontier_healthy(finterval)
-                    if noop_frac >= 0.3 and b < self._max_batch \
-                            and not frontier_healthy:
-                        b *= 2
-                    elif noop_frac < 0.1 and b > 1:
-                        b //= 2
-                    if b != self._group.batch_size:
-                        self._group.batch_size = b
-                        seen = max((self.stats[s.name].tuples
-                                    for _qi, s in self._group.live_items()),
-                                   default=0)
-                        self.batch_size_log.append((seen, b))
-            self._adapt_marks = (qr, uqr)
-
-        for sgt in stream:
-            # lazy expiration at slide boundaries (eager evaluation)
-            if sgt.ts >= self._next_expiry:
-                flush_dense()
-                flush_deletes()
+            def flush_deletes() -> None:
+                """Dispatch the buffered negative tuples as one micro-batch
+                through the engine's chunked delete path (frontier cone per
+                chunk when the frontier is on). Only one of dense_buf/del_buf
+                is ever non-empty — the event loop flushes the other before
+                buffering — so stream order is preserved."""
+                if not del_buf:
+                    return
                 resolve_pending()
-                if self._group is not None:
-                    self._group.expire(sgt.ts)
-                for eng in self._ref_engines.values():
-                    eng.expire(sgt.ts)
-                while self._next_expiry <= sgt.ts:
-                    self._next_expiry += self.slide
-                adapt_batch(mark_interval())
-            # snapshot BEFORE the dense step: a fallback fired by this very
-            # event must not re-feed the event to its new reference engine
-            refs_this_event = list(self._ref_engines.items())
-            if self._group is not None:
-                if sgt.op == "+":
-                    flush_deletes()
-                    dense_buf.append(sgt)
-                    if (not self._adaptive_batch
-                            or len(dense_buf) >= self._group.batch_size):
-                        flush_dense()
-                else:
+                batch = [(s.src, s.dst, s.label, s.ts) for s in del_buf]
+                inv = self._group.delete_batch(batch)
+                for qi, spec in self._group.live_items():
+                    self.stats[spec.name].tuples += len(batch)
+                    invalidated[spec.name] |= inv[qi]
+                deletions[0] += len(batch)
+                del_buf.clear()
+                self._maybe_fallback(fallbacks, lambda: resolve_pending(0))
+
+            def mark_interval() -> Dict[str, object]:
+                """Per-interval frontier telemetry: append the delta since the
+                last slide boundary to :attr:`frontier_log` and hand it to the
+                batch steering below."""
+                delta = self._frontier_delta()
+                seen = max((self.stats[s.name].tuples
+                            for _qi, s in self._group.live_items()),
+                           default=0) if self._group is not None else 0
+                if delta:
+                    self.frontier_log.append((seen, delta))
+                if (self._group is not None
+                        and self._group.executor.adj_layout == "ell"):
+                    self.adjacency_log.append(
+                        (seen, self._group.executor.adjacency_stats))
+                if (self._group is not None
+                        and self._group.executor.dist_layout == "row_sparse"):
+                    self.dist_log.append(
+                        (seen, self._group.executor.dist_stats))
+                return delta
+
+            def adapt_batch(finterval: Dict[str, object]) -> None:
+                """Steer the dense micro-batch size from the interval's no-op
+                relaxation tail AND the frontier telemetry (see docstring)."""
+                if not self._adaptive_batch or self._group is None:
+                    return
+                ex = self._group.executor
+                qr, uqr = ex.query_rounds_total, ex.unmasked_query_rounds_total
+                if self._adapt_marks is not None:
+                    dqr = qr - self._adapt_marks[0]
+                    duqr = uqr - self._adapt_marks[1]
+                    if duqr > 0:
+                        noop_frac = 1.0 - dqr / duqr
+                        b = self._group.batch_size
+                        # the no-op tail argues for a bigger B (dispatch
+                        # overhead dominates useful work) — but when the
+                        # frontier is live and healthy (tiny row occupancy, no
+                        # overflow pressure) each dispatch is ALREADY cheap in
+                        # proportion to its dirty rows, so growing B would
+                        # trade exactness (batch-boundary skew) for little:
+                        # hold B instead
+                        frontier_healthy = self._frontier_healthy(finterval)
+                        if noop_frac >= 0.3 and b < self._max_batch \
+                                and not frontier_healthy:
+                            b *= 2
+                        elif noop_frac < 0.1 and b > 1:
+                            b //= 2
+                        if b != self._group.batch_size:
+                            self._group.batch_size = b
+                            seen = max((self.stats[s.name].tuples
+                                        for _qi, s in self._group.live_items()),
+                                       default=0)
+                            self.batch_size_log.append((seen, b))
+                self._adapt_marks = (qr, uqr)
+
+            n_sgts = 0
+            for sgt in stream:
+                n_sgts += 1
+                # lazy expiration at slide boundaries (eager evaluation)
+                if sgt.ts >= self._next_expiry:
                     flush_dense()
-                    del_buf.append(sgt)
-                    if (not self._adaptive_batch
-                            or len(del_buf) >= self._group.batch_size):
+                    flush_deletes()
+                    resolve_pending()
+                    with telemetry.span("service.expire"):
+                        if self._group is not None:
+                            self._group.expire(sgt.ts)
+                        for eng in self._ref_engines.values():
+                            eng.expire(sgt.ts)
+                        while self._next_expiry <= sgt.ts:
+                            self._next_expiry += self.slide
+                        adapt_batch(mark_interval())
+                # snapshot BEFORE the dense step: a fallback fired by this very
+                # event must not re-feed the event to its new reference engine
+                refs_this_event = list(self._ref_engines.items())
+                if self._group is not None:
+                    if sgt.op == "+":
                         flush_deletes()
-            for name, eng in refs_this_event:
-                t0 = time.perf_counter_ns() if record_latency else 0
-                if sgt.op == "+":
-                    res = eng.insert(sgt.src, sgt.dst, sgt.label, sgt.ts)
-                    new_results[name] |= res
-                else:
-                    # expire to the deletion's own clock first, as the
-                    # dense delete judges validity at it: a pair the
-                    # window already dropped is not invalidated by the
-                    # negative tuple (lazy expiry would report it here)
-                    eng.expire(sgt.ts)
-                    inv = eng.delete(sgt.src, sgt.dst, sgt.label, sgt.ts)
-                    if inv:
-                        invalidated[name] |= set(inv)
+                        dense_buf.append(sgt)
+                        if (not self._adaptive_batch
+                                or len(dense_buf) >= self._group.batch_size):
+                            flush_dense()
+                    else:
+                        flush_dense()
+                        del_buf.append(sgt)
+                        if (not self._adaptive_batch
+                                or len(del_buf) >= self._group.batch_size):
+                            flush_deletes()
+                for name, eng in refs_this_event:
+                    if sgt.op == "+":
+                        res = eng.insert(sgt.src, sgt.dst, sgt.label, sgt.ts)
+                        new_results[name] |= res
+                    else:
+                        # expire to the deletion's own clock first, as the
+                        # dense delete judges validity at it: a pair the
+                        # window already dropped is not invalidated by the
+                        # negative tuple (lazy expiry would report it here)
+                        eng.expire(sgt.ts)
+                        inv = eng.delete(sgt.src, sgt.dst, sgt.label, sgt.ts)
+                        if inv:
+                            invalidated[name] |= set(inv)
+                    self.stats[name].tuples += 1
+            flush_dense()
+            flush_deletes()
+            resolve_pending()
+            for name in self.stats:
                 st = self.stats[name]
-                st.tuples += 1
-                if record_latency:
-                    st.latencies_us.append((time.perf_counter_ns() - t0) / 1e3)
-        flush_dense()
-        flush_deletes()
-        resolve_pending()
-        for name in self.stats:
-            st = self.stats[name]
-            if name in self._dense_specs or name in self._ref_engines:
-                st.results = len(self.results(name))
-                st.conflicted = st.conflicted or self._conflicted(name)
-            if st.latencies_us:
-                lat = sorted(st.latencies_us)
-                st.p99_us = lat[min(int(0.99 * len(lat)), len(lat) - 1)]
-        fstats: Dict[str, object] = {}
-        if call_mark and self._group is not None:
-            fstats = self._stats_delta(
-                self._group.executor.frontier_stats, call_mark)
-        return IngestReport(new_results, invalidated, fallbacks, fstats,
-                            deletions=deletions[0])
+                if name in self._dense_specs or name in self._ref_engines:
+                    st.results = len(self.results(name))
+                    st.conflicted = st.conflicted or self._conflicted(name)
+            fstats: Dict[str, object] = {}
+            if call_mark and self._group is not None:
+                fstats = self._stats_delta(
+                    self._group.executor.frontier_stats, call_mark)
+            span.value = n_sgts
+            return IngestReport(new_results, invalidated, fallbacks, fstats,
+                                deletions=deletions[0])
 
     def results(self, name: str) -> Set[Tuple]:
         if name in self._dense_specs:
@@ -771,63 +755,65 @@ class PersistentQueryService:
         makes snapshot a sequence point: state and results agree."""
         from ..checkpoint import ckpt
 
-        self._ensure_group()
-        if self._group is not None:
-            # belt-and-braces with engine.state_arrays()/results_state()
-            # (each drains too): ONE sequence point, visible at the
-            # service boundary, regression-pinned in tests/test_fault.py
-            self._group._drain_pending()
-        state: Dict[str, object] = {}
-        extra: Dict[str, object] = {
-            "step": step,
-            "next_expiry": self._next_expiry,
-            "reference": sorted(self._ref_engines),
-        }
-        if wal_lsn is not None:
-            extra["wal_lsn"] = int(wal_lsn)
-        if extra_meta:
-            # caller metadata (e.g. the supervisor's churn catalog) rides
-            # the manifest; reserved keys stay ours
-            for k, v in extra_meta.items():
-                extra.setdefault(k, v)
-        if self._group is not None:
-            state["dense_group"] = self._group.state_arrays()
-            extra["dense"] = {
-                # the LIVE query set, lane by lane (None = inert padding):
-                # restore matches lanes by name, so the restoring group may
-                # have a different bucketed-Q layout (or executor shard
-                # quantum)
-                "order": [s.name if s is not None else None
-                          for s in self._group.lane_specs],
-                "labels": list(self._group.labels),
-                "interner": self._group.interner_state(),
-                # learned capacity occupancy (all ×2-bucketed): a restored
-                # service starts at these instead of re-learning them from
-                # overflow pressure — frontier_cap from "auto" growth,
-                # dist_cap from row-sparse drains, ell_cap from adjacency
-                # packs; harmless no-ops for layouts/modes that are off
-                "capacities": {
-                    "frontier_cap": int(self._group.executor.frontier_cap),
-                    "ell_cap": int(self._group.executor.ell_cap),
-                    "dist_cap": int(self._group.executor.dist_cap),
-                    "dist_ovf_cap": (
-                        int(self._group.executor.dist_ovf_cap)
-                        if self._group.executor.dist_ovf_cap is not None
-                        else None),
-                },
-                **self._group.results_state(),
+        with telemetry.span("checkpoint.capture") as span:
+            self._ensure_group()
+            if self._group is not None:
+                # belt-and-braces with engine.state_arrays()/results_state()
+                # (each drains too): ONE sequence point, visible at the
+                # service boundary, regression-pinned in tests/test_fault.py
+                self._group._drain_pending()
+            state: Dict[str, object] = {}
+            extra: Dict[str, object] = {
+                "step": step,
+                "next_expiry": self._next_expiry,
+                "reference": sorted(self._ref_engines),
             }
-        for name, eng in self._ref_engines.items():
-            state[f"refeng.{name}"] = ckpt.pickle_leaf(eng)
-        if async_save:
-            ckpt.async_save(directory, step, state, extra=extra,
-                            _crash_after=_crash_after)
-        else:
-            # an async save still writing to `directory` may target the
-            # same step dir: join it first so the two never interleave
-            ckpt.wait_pending(directory)
-            ckpt.save(directory, step, state, extra=extra,
-                      _crash_after=_crash_after)
+            if wal_lsn is not None:
+                extra["wal_lsn"] = int(wal_lsn)
+            if extra_meta:
+                # caller metadata (e.g. the supervisor's churn catalog) rides
+                # the manifest; reserved keys stay ours
+                for k, v in extra_meta.items():
+                    extra.setdefault(k, v)
+            if self._group is not None:
+                state["dense_group"] = self._group.state_arrays()
+                extra["dense"] = {
+                    # the LIVE query set, lane by lane (None = inert padding):
+                    # restore matches lanes by name, so the restoring group may
+                    # have a different bucketed-Q layout (or executor shard
+                    # quantum)
+                    "order": [s.name if s is not None else None
+                              for s in self._group.lane_specs],
+                    "labels": list(self._group.labels),
+                    "interner": self._group.interner_state(),
+                    # learned capacity occupancy (all ×2-bucketed): a restored
+                    # service starts at these instead of re-learning them from
+                    # overflow pressure — frontier_cap from "auto" growth,
+                    # dist_cap from row-sparse drains, ell_cap from adjacency
+                    # packs; harmless no-ops for layouts/modes that are off
+                    "capacities": {
+                        "frontier_cap": int(self._group.executor.frontier_cap),
+                        "ell_cap": int(self._group.executor.ell_cap),
+                        "dist_cap": int(self._group.executor.dist_cap),
+                        "dist_ovf_cap": (
+                            int(self._group.executor.dist_ovf_cap)
+                            if self._group.executor.dist_ovf_cap is not None
+                            else None),
+                    },
+                    **self._group.results_state(),
+                }
+            for name, eng in self._ref_engines.items():
+                state[f"refeng.{name}"] = ckpt.pickle_leaf(eng)
+            span.value = sum(int(x.nbytes) for x in jax.tree.leaves(state))
+            if async_save:
+                ckpt.async_save(directory, step, state, extra=extra,
+                                _crash_after=_crash_after)
+            else:
+                # an async save still writing to `directory` may target the
+                # same step dir: join it first so the two never interleave
+                ckpt.wait_pending(directory)
+                ckpt.save(directory, step, state, extra=extra,
+                          _crash_after=_crash_after)
 
     def restore(self, directory: str) -> int:
         from ..checkpoint import ckpt

@@ -57,6 +57,7 @@ import random
 import time
 from typing import Callable, Deque, Dict, Iterable, List, Optional, Set, Tuple
 
+from .. import telemetry
 from ..checkpoint import ckpt
 from ..checkpoint.ckpt import SimulatedCrash
 from .stream import SGT
@@ -430,12 +431,15 @@ class ServiceSupervisor:
             self._process_batch(self.queue.take(self.batch_events))
 
     def _process_batch(self, batch: List[SGT]) -> None:
-        lsn = self.wal.append(batch)  # durable BEFORE the engine sees it
-        try:
-            self._dispatch(lsn, batch, replaying=False)
-            self._after_dispatch_bookkeeping()
-        except (InjectedCrash, SimulatedCrash):
-            self._recover()
+        # every span under this batch carries the lsn its WAL record gets
+        with telemetry.batch(self.wal.last_lsn + 1), \
+                telemetry.span("supervisor.batch", len(batch)):
+            lsn = self.wal.append(batch)  # durable BEFORE the engine sees it
+            try:
+                self._dispatch(lsn, batch, replaying=False)
+                self._after_dispatch_bookkeeping()
+            except (InjectedCrash, SimulatedCrash):
+                self._recover()
 
     def _after_dispatch_bookkeeping(self) -> None:
         self._dispatches += 1
@@ -449,29 +453,28 @@ class ServiceSupervisor:
     def _dispatch(self, lsn: int, batch: List[SGT], replaying: bool) -> None:
         plan = self.plan
         hook = "during_replay" if replaying else "before_dispatch"
-        if plan is not None:
-            if plan.take_crash(hook, lsn):
-                raise InjectedCrash(f"{hook} lsn={lsn}")
-            delay = plan.take_sleep(lsn)
-            if delay > 0:
-                time.sleep(delay)  # straggler: observed below as wall time
-        attempts = 0
-        while True:
-            t0 = time.monotonic()
-            try:
-                if plan is not None and plan.take_transient(lsn):
-                    raise TransientDecodeError(f"transient at lsn={lsn}")
-                report = self.service.ingest(batch)
-                break
-            except TransientDecodeError:
-                attempts += 1
-                self.retries += 1
-                if attempts > self.max_retries:
-                    raise
-                if self.backoff_s > 0:
-                    time.sleep(self.backoff_s * (2 ** (attempts - 1)))
-        dt = time.monotonic() - t0
-        if self.monitor.observe(self._dispatches, dt):
+        if plan is not None and plan.take_crash(hook, lsn):
+            raise InjectedCrash(f"{hook} lsn={lsn}")
+        with telemetry.span("supervisor.dispatch", len(batch)) as span:
+            if plan is not None:
+                delay = plan.take_sleep(lsn)
+                if delay > 0:
+                    time.sleep(delay)  # straggler: observed below
+            attempts = 0
+            while True:
+                try:
+                    if plan is not None and plan.take_transient(lsn):
+                        raise TransientDecodeError(f"transient at lsn={lsn}")
+                    report = self.service.ingest(batch)
+                    break
+                except TransientDecodeError:
+                    attempts += 1
+                    self.retries += 1
+                    if attempts > self.max_retries:
+                        raise
+                    if self.backoff_s > 0:
+                        time.sleep(self.backoff_s * (2 ** (attempts - 1)))
+        if self.monitor.observe(self._dispatches, span.seconds):
             self.stragglers.append(lsn)
             if self.on_straggler is not None:
                 self.on_straggler(lsn)
@@ -573,7 +576,9 @@ class ServiceSupervisor:
                 n_records += 1
                 if rec.kind == "batch":
                     n_events += len(rec.events)
-                    self._dispatch(rec.lsn, list(rec.events), replaying=True)
+                    with telemetry.batch(rec.lsn):
+                        self._dispatch(rec.lsn, list(rec.events),
+                                       replaying=True)
                 else:
                     self._apply_churn(rec.kind, rec.meta["name"],
                                       {k: v for k, v in rec.meta.items()

@@ -39,6 +39,7 @@ import os
 import zlib
 from typing import Iterator, List, Optional, Sequence, Tuple
 
+from .. import telemetry
 from ..core.engine import _decode_vertex, _encode_vertex
 from .stream import SGT
 
@@ -130,18 +131,21 @@ class WriteAheadLog:
         return self._write({"kind": kind, "name": name, "meta": meta or {}})
 
     def _write(self, payload: dict) -> int:
-        self._last_lsn += 1
-        payload["lsn"] = self._last_lsn
-        blob = json.dumps(payload, sort_keys=True).encode("utf-8")
-        line = f"{zlib.crc32(blob) & 0xFFFFFFFF:08x} ".encode("ascii") \
-            + blob + b"\n"
-        if self._fh is None or self._seg_count >= self.segment_records:
-            self._rotate(self._last_lsn)
-        self._fh.write(line)
-        self._fh.flush()
-        if self.fsync:
-            os.fsync(self._fh.fileno())
-        self._seg_count += 1
+        with telemetry.span("wal.append") as span:
+            self._last_lsn += 1
+            payload["lsn"] = self._last_lsn
+            blob = json.dumps(payload, sort_keys=True).encode("utf-8")
+            line = f"{zlib.crc32(blob) & 0xFFFFFFFF:08x} ".encode("ascii") \
+                + blob + b"\n"
+            span.value = len(line)
+            if self._fh is None or self._seg_count >= self.segment_records:
+                self._rotate(self._last_lsn)
+            self._fh.write(line)
+            self._fh.flush()
+            if self.fsync:
+                with telemetry.span("wal.fsync"):
+                    os.fsync(self._fh.fileno())
+            self._seg_count += 1
         return self._last_lsn
 
     def _rotate(self, first_lsn: int) -> None:

@@ -261,6 +261,25 @@ def test_r1_reaches_through_helper_calls():
         "the jitted caller")
 
 
+def test_r1_flags_telemetry_in_traced_code():
+    src = """\
+import jax
+from repro import telemetry
+
+@jax.jit
+def step(x):
+    with telemetry.span("step"):
+        return x + 1
+
+def host(x):
+    with telemetry.span("host"):
+        return step(x)
+"""
+    hits = _hits(src, "R1")
+    assert len(hits) == 1 and "telemetry.span" in hits[0].message
+    assert "`step`" in hits[0].message
+
+
 def test_r1_ignores_host_side_numpy():
     hits = _hits(R1_CLEAN + "\n", "R1")
     assert not hits  # host_prep's np.asarray is outside the jit boundary
