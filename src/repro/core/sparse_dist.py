@@ -251,16 +251,18 @@ def rsd_scatter_rows(sd: RowSparseDist, rows: jax.Array,
     the write is a full-row overwrite — exact even when a row shrinks:
 
     * rows already in the overflow table overwrite their table row;
-    * rows whose finite count fits ``dist_cap`` overwrite their slots
-      (cleared first so stale high-rank entries die);
+    * rows whose finite count fits ``dist_cap`` overwrite all C slots
+      with their finite entries in column order, free past the count
+      (so stale high-rank entries die);
     * rows newly exceeding ``dist_cap`` claim fresh table slots at the
       cursor (their slots are cleared — a row lives in one region);
     * claims past ``ovf_cap`` drop the row and count into ``lost`` —
       unreachable under the host budget (``Executor._reserve_dist``).
 
+    Every write is row-granular: at most Q·F index tuples per scatter.
     Valid frontier rows are unique per lane (``pack_frontier`` packs a
     mask), so the scatters are collision-free; masked padding slots are
-    routed to drop sentinels.
+    routed to drop sentinels. Free slots written here get ``idx`` 0.
     """
     q, f, n, k = slab.shape
     e = n * k
@@ -283,17 +285,23 @@ def rsd_scatter_rows(sd: RowSparseDist, rows: jax.Array,
     ovf_ts2 = sd.ovf_ts.at[dest].set(flat, mode="drop")
     n_new = jnp.sum(new_claim).astype(jnp.int32)
     dropped = jnp.sum(new_claim & (sd.ovf_ptr + crank >= r)).astype(jnp.int32)
-    # -- slot writes: clear every valid row, then pack the fitting ones
-    clear_row = jnp.where(rowmask, rows, n)
-    ts1 = sd.ts.at[lane, clear_row].set(NEG_INF, mode="drop")
+    # -- slot writes: one full C-wide row per valid slot. Slot j of a
+    # fitting row holds its j-th finite column, which is the number of
+    # columns whose inclusive finite count is <= j (a fused
+    # compare-reduce; nothing (Q, F, C, E)-sized is materialised).
+    # Rows routed to the table get an all-free row: the clear.
+    incl = jnp.cumsum(finite, axis=-1, dtype=jnp.int32)   # (Q, F, E)
+    slot = jnp.arange(c, dtype=jnp.int32)
+    col = jnp.sum(incl[:, :, None, :] <= slot[:, None], axis=-1,
+                  dtype=jnp.int32)                        # (Q, F, C)
     write_slots = rowmask & fits & ~in_ovf
-    srow = jnp.where(write_slots, rows, n)[:, :, None]    # n = drop sentinel
-    rank = jnp.cumsum(finite, axis=-1) - 1
-    pos = jnp.where(finite & fits[:, :, None], rank, c)
-    cols = jnp.broadcast_to(jnp.arange(e, dtype=jnp.int32), (q, f, e))
-    lane3 = lane[:, :, None]
-    idx2 = sd.idx.at[lane3, srow, pos].set(cols, mode="drop")
-    ts2 = ts1.at[lane3, srow, pos].set(flat, mode="drop")
+    used = write_slots[:, :, None] & (slot < counts[:, :, None])
+    col = jnp.where(used, col, 0)
+    row_ts = jnp.where(used, jnp.take_along_axis(flat, col, axis=-1),
+                       NEG_INF)
+    wrow = jnp.where(rowmask, rows, n)                    # n = drop sentinel
+    idx2 = sd.idx.at[lane, wrow].set(col, mode="drop")
+    ts2 = sd.ts.at[lane, wrow].set(row_ts, mode="drop")
     return RowSparseDist(idx2, ts2, ovf_rows2, ovf_ts2,
                          jnp.minimum(sd.ovf_ptr + n_new, r),
                          sd.lost + dropped)

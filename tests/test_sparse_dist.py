@@ -21,6 +21,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8 so the lane-sharded
 row slabs compose with the in-jit densify).
 """
 import random
+import re
 
 import numpy as np
 import pytest
@@ -135,6 +136,146 @@ def test_gather_scatter_round_trip(seed):
                                          dense[qi, r] + 1.0, dense[qi, r])
     np.testing.assert_array_equal(np.asarray(rsd_to_dense(sd2)), want_d)
     assert int(sd2.lost) == 0
+
+
+def _scatter_rows_by_element(sd, rows, rowmask, slab):
+    """Reference write-back: clear every valid row, then place each finite
+    slab entry at its cumsum rank with one index tuple per slab element
+    (O(Q·F·N·K) scattered updates). ``rsd_scatter_rows`` must store the
+    same state while writing whole rows."""
+    q, f, n, k = slab.shape
+    e = n * k
+    c = sd.idx.shape[2]
+    r = sd.ovf_rows.shape[0]
+    flat = slab.reshape(q, f, e)
+    finite = flat > NEG_INF
+    counts = jnp.sum(finite, axis=-1)
+    fits = counts <= c
+    lane = jnp.arange(q)[:, None]
+    key = lane * n + rows
+    match = key[..., None] == sd.ovf_rows
+    in_ovf, oslot = jnp.any(match, axis=-1), jnp.argmax(match, axis=-1)
+    new_claim = rowmask & ~fits & ~in_ovf
+    crank = (jnp.cumsum(new_claim.reshape(-1)) - 1).reshape(q, f)
+    dest = jnp.where(in_ovf, oslot, sd.ovf_ptr + crank)
+    write_ovf = rowmask & (in_ovf | ~fits)
+    dest = jnp.where(write_ovf, jnp.minimum(dest, r), r)
+    ovf_rows2 = sd.ovf_rows.at[dest].set(key, mode="drop")
+    ovf_ts2 = sd.ovf_ts.at[dest].set(flat, mode="drop")
+    n_new = jnp.sum(new_claim).astype(jnp.int32)
+    dropped = jnp.sum(new_claim & (sd.ovf_ptr + crank >= r)).astype(jnp.int32)
+    clear_row = jnp.where(rowmask, rows, n)
+    ts1 = sd.ts.at[lane, clear_row].set(NEG_INF, mode="drop")
+    write_slots = rowmask & fits & ~in_ovf
+    srow = jnp.where(write_slots, rows, n)[:, :, None]
+    rank = jnp.cumsum(finite, axis=-1) - 1
+    pos = jnp.where(finite & fits[:, :, None], rank, c)
+    cols = jnp.broadcast_to(jnp.arange(e, dtype=jnp.int32), (q, f, e))
+    lane3 = lane[:, :, None]
+    idx2 = sd.idx.at[lane3, srow, pos].set(cols, mode="drop")
+    ts2 = ts1.at[lane3, srow, pos].set(flat, mode="drop")
+    return RowSparseDist(idx2, ts2, ovf_rows2, ovf_ts2,
+                         jnp.minimum(sd.ovf_ptr + n_new, r),
+                         sd.lost + dropped)
+
+
+def _row_with(rng, e, count):
+    """One flattened row with ``count`` finite entries at random columns."""
+    row = np.full(e, NEG_INF, np.float32)
+    row[rng.choice(e, count, replace=False)] = rng.integers(1, 60, count)
+    return row
+
+
+# case -> (stored row counts, slab row counts, dist_cap of the stored
+# rows, ovf_cap, share of slab slots masked); counts are (lo, hi) inclusive
+_SCATTER_CASES = {
+    "fit": ((0, 4), (0, 4), 4, 16, 0.0),
+    "shrink": ((4, 4), (0, 2), 4, 16, 0.0),
+    "at_cap": ((0, 3), (4, 4), 4, 16, 0.0),
+    "newly_over": ((0, 4), (5, 9), 4, 16, 0.0),
+    "in_table": ((5, 9), (0, 9), 4, 64, 0.0),
+    "masked": ((0, 6), (0, 6), 4, 32, 0.5),
+    "table_full": ((0, 5), (5, 9), 4, 3, 0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCATTER_CASES))
+@pytest.mark.parametrize("seed", range(3))
+def test_scatter_rows_matches_element_scatter(case, seed):
+    """Row-granular write-back stores exactly what the element scatter
+    stored: same ts, overflow table, cursor and lost count, and the same
+    idx in every occupied slot (free slots' idx may differ)."""
+    stored, fresh, cap, ovf_cap, masked = _SCATTER_CASES[case]
+    rng = np.random.default_rng(1000 * seed + sorted(_SCATTER_CASES).index(case))
+    q, n, k, f = 3, 12, 2, 5
+    e = n * k
+    dense = np.full((q, n, e), NEG_INF, np.float32)
+    for qi in range(q):
+        for x in range(n):
+            dense[qi, x] = _row_with(rng, e, rng.integers(stored[0],
+                                                          stored[1] + 1))
+    if case == "table_full":  # leave one table slot: later claims are lost
+        dense[:, :, :] = np.where(np.arange(e) < cap, dense, NEG_INF)
+        dense[0, 0] = _row_with(rng, e, cap + 2)
+        dense[0, 1] = _row_with(rng, e, cap + 2)
+    sd = _dev(pack_rows(dense.reshape(q, n, n, k), cap, ovf_cap))
+    rows = np.stack([np.sort(rng.choice(n, f, replace=False))
+                     for _ in range(q)]).astype(np.int32)
+    rowmask = rng.random((q, f)) >= masked
+    if masked:
+        rows[0, 0], rowmask[0, 0] = 0, True   # a valid row 0 beside padding
+        rowmask[0, 1:3] = False
+    rows = np.where(rowmask, rows, 0).astype(np.int32)   # pack_frontier pads 0
+    slab = np.stack([np.stack([_row_with(rng, e, rng.integers(fresh[0],
+                                                              fresh[1] + 1))
+                               for _ in range(f)]) for _ in range(q)])
+    args = (sd, jnp.asarray(rows), jnp.asarray(rowmask),
+            jnp.asarray(slab.reshape(q, f, n, k)))
+    want = jax.device_get(_scatter_rows_by_element(*args))
+    got = jax.device_get(rsd_scatter_rows(*args))
+    for name in ("ts", "ovf_rows", "ovf_ts", "ovf_ptr", "lost"):
+        np.testing.assert_array_equal(getattr(got, name), getattr(want, name),
+                                      err_msg=name)
+    occupied = want.ts > NEG_INF
+    np.testing.assert_array_equal(got.idx[occupied], want.idx[occupied])
+    if case == "table_full":
+        assert int(got.lost) > 0
+    if case in ("newly_over", "in_table"):
+        assert int(got.ovf_ptr) > 0
+
+
+def _scatter_index_tuples(module_text):
+    """Index tuples of every ``stablehlo.scatter`` in a lowered module."""
+    out = []
+    pat = re.compile(r'"stablehlo\.scatter".*?index_vector_dim = (\d+).*?'
+                     r'\}\) : \(tensor<[^>]*>, tensor<([0-9x]*)x?i\d+>',
+                     re.S)
+    for m in pat.finditer(module_text):
+        ivd = int(m.group(1))
+        dims = [int(d) for d in m.group(2).split("x") if d]
+        total = int(np.prod(dims, dtype=np.int64))
+        out.append(total // dims[ivd] if ivd < len(dims) else total)
+    return out
+
+
+def test_scatter_rows_writes_whole_rows():
+    """No scatter of the lowered write-back has more than Q·F·C index
+    tuples: the per-element write (Q·F·N·K tuples) cannot come back."""
+    q, f, n, k, c, r = 3, 8, 64, 4, 8, 16
+    s = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    args = (RowSparseDist(s((q, n, c), i32), s((q, n, c), f32),
+                          s((r,), i32), s((r, n * k), f32), s((), i32),
+                          s((), i32)),
+            s((q, f), i32), s((q, f), jnp.bool_), s((q, f, n, k), f32))
+    tuples = _scatter_index_tuples(
+        jax.jit(rsd_scatter_rows).lower(*args).as_text())
+    assert len(tuples) == 4, tuples   # idx, ts, ovf_rows, ovf_ts
+    assert max(tuples) <= q * f * c, tuples
+    # the reading sees the element scatters of the reference
+    ref = _scatter_index_tuples(
+        jax.jit(_scatter_rows_by_element).lower(*args).as_text())
+    assert max(ref) == q * f * n * k, ref
 
 
 def test_seed_gathered_matches_dense_seed():
