@@ -627,9 +627,12 @@ class BatchedDenseRPQEngine:
                     j += 1
                 sp.value = j
             self._host_now = max(self._host_now, chunk_now)
+            if j < len(edges):
+                telemetry.count("engine.bypassed", len(edges) - j, sp.t0_ns)
             if j == 0:
                 # still advance the clock
-                self.executor.advance_clock(chunk_now)
+                with telemetry.span("engine.bypass", len(edges)):
+                    self.executor.advance_clock(chunk_now)
                 return
             new = self.executor.ingest_batch(
                 src, dst, lab, ts, mask, chunk_now, self.tables
@@ -703,9 +706,12 @@ class BatchedDenseRPQEngine:
                 mask[j] = True
                 j += 1
             sp.value = j
+        if j < len(edges):
+            telemetry.count("engine.bypassed", len(edges) - j, sp.t0_ns)
         if j == 0:
             # still advance the clock (every event timestamp moves it)
-            self.executor.advance_clock(chunk_now)
+            with telemetry.span("engine.bypass", len(edges)):
+                self.executor.advance_clock(chunk_now)
             return
         invalidated = self.executor.delete_batch(
             src, dst, lab, mask, chunk_now, self.tables)
@@ -726,19 +732,21 @@ class BatchedDenseRPQEngine:
         self.expire()
 
     def _recycle(self, live: np.ndarray) -> None:
-        dead_slots = [
-            s for s, vtx in enumerate(self.vertex_of)
-            if vtx is not None and not bool(live[s])
-            and s not in self._chunk_pinned  # chunk-local: edge not landed yet
-        ]
-        if not dead_slots:
-            return
-        self.executor.clear_slots(dead_slots)
-        for s in dead_slots:
-            vtx = self.vertex_of[s]
-            self.vertex_of[s] = None
-            del self.slot_of[vtx]
-            self.free.append(s)
+        with telemetry.span("engine.recycle") as sp:
+            dead_slots = [
+                s for s, vtx in enumerate(self.vertex_of)
+                if vtx is not None and not bool(live[s])
+                and s not in self._chunk_pinned  # chunk-local: edge not landed yet
+            ]
+            sp.value = len(dead_slots)
+            if not dead_slots:
+                return
+            self.executor.clear_slots(dead_slots)
+            for s in dead_slots:
+                vtx = self.vertex_of[s]
+                self.vertex_of[s] = None
+                del self.slot_of[vtx]
+                self.free.append(s)
 
     # -- result decoding ------------------------------------------------------
 
