@@ -627,12 +627,8 @@ class BatchedDenseRPQEngine:
                     j += 1
                 sp.value = j
             self._host_now = max(self._host_now, chunk_now)
-            if j < len(edges):
-                telemetry.count("engine.bypassed", len(edges) - j, sp.t0_ns)
             if j == 0:
-                # still advance the clock
-                with telemetry.span("engine.bypass", len(edges)):
-                    self.executor.advance_clock(chunk_now)
+                self._bypass(len(edges), chunk_now)
                 return
             new = self.executor.ingest_batch(
                 src, dst, lab, ts, mask, chunk_now, self.tables
@@ -706,18 +702,20 @@ class BatchedDenseRPQEngine:
                 mask[j] = True
                 j += 1
             sp.value = j
-        if j < len(edges):
-            telemetry.count("engine.bypassed", len(edges) - j, sp.t0_ns)
         if j == 0:
-            # still advance the clock (every event timestamp moves it)
-            with telemetry.span("engine.bypass", len(edges)):
-                self.executor.advance_clock(chunk_now)
+            self._bypass(len(edges), chunk_now)
             return
         invalidated = self.executor.delete_batch(
             src, dst, lab, mask, chunk_now, self.tables)
         inv = _fetch_result(invalidated)
         for qi, _spec in self.live_items():
             out[qi] |= self._decode_pairs(inv[qi], bool(self._simple[qi]))
+
+    def _bypass(self, n_events: int, chunk_now: float) -> None:
+        """A chunk that packed no tuple still advances the stream clock:
+        every event timestamp moves it."""
+        with telemetry.span("engine.bypass", n_events):
+            self.executor.advance_clock(chunk_now)
 
     def expire(self, tau: Optional[float] = None) -> None:
         """Slide-boundary maintenance: adjacency masking + slot recycling.

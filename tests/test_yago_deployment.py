@@ -1,5 +1,6 @@
-"""The Yago2s deployment (``benchmarks/stream_bench/configs/yago-table2.json``)
-at CPU size, on the service's normal path.
+"""The ``yago_like`` many-predicate stream at CPU size, on the service's
+normal path, configured as ``benchmarks/stream_bench/configs/yago-table2.json``
+configures it.
 
 A ``yago_like`` stream (100 predicates at Zipf frequency, uniform
 endpoints, 2% deletions) feeds ``PersistentQueryService`` with the eleven
@@ -126,8 +127,8 @@ def test_bypass_advances_only_the_clock_and_expiry_still_recycles():
     """Chunks that pack no tuple (labels outside the alphabet, deletes of
     an outside label or of vertices never interned) leave the device
     state as it was but move its clock; the expiry they trigger still
-    drops the window's edges and recycles their slots. The spans and the
-    counter carry the chunk's event count and the dead-slot count."""
+    drops the window's edges and recycles their slots. The spans carry
+    the chunk's event count and the dead-slot count."""
     svc = _service()
     graph = [(0, 1, "p0"), (1, 2, "p1"), (2, 3, "p1"), (4, 5, "p2"),
              (5, 6, "p0"), (6, 7, "p1")]
@@ -153,9 +154,7 @@ def test_bypass_advances_only_the_clock_and_expiry_still_recycles():
     assert len(group.slot_of) == n_live
     spans = telemetry.records("engine.bypass", t0, t1)[0]
     assert [r.value for r in spans] == [1] * len(bypass)
-    counts = telemetry.records("engine.bypassed", t0, t1)[0]
-    assert sum(c.n for c in counts) == len(bypass)
-    assert all(t0 <= c.t_ns <= t1 for c in counts)
+    assert all(t0 <= r.t0_ns <= r.t1_ns <= t1 for r in spans)
     assert not telemetry.records("engine.recycle", t0, t1)[0]
 
     # a bypassed event past the window: expiry drops every edge above
@@ -176,8 +175,9 @@ def test_bypass_advances_only_the_clock_and_expiry_still_recycles():
 
 def test_bypassed_counts_the_events_outside_the_alphabet_of_mixed_chunks():
     """With B = 2 a chunk can mix events inside and outside the alphabet:
-    it dispatches, records no ``engine.bypass``, and counts its outside
-    events; a chunk with none inside counts and bypasses both."""
+    it dispatches and records no ``engine.bypass``, and its
+    ``engine.intern`` span counts only the events inside; a chunk with
+    none inside bypasses with both."""
     svc = PersistentQueryService(window=WINDOW, slide=SLIDE,
                                  frontier="auto", adj_layout="ell",
                                  dist_layout="row_sparse")
@@ -189,7 +189,7 @@ def test_bypassed_counts_the_events_outside_the_alphabet_of_mixed_chunks():
                               (4, 5, "p8", 0.5), (5, 6, "p9", 0.625)])
     t1 = time.perf_counter_ns()
     assert new[group.lane_of("q")] == {(0, 2)}
-    counts = telemetry.records("engine.bypassed", t0, t1)[0]
-    assert [c.n for c in counts] == [1, 2]
+    interns = telemetry.records("engine.intern", t0, t1)[0]
+    assert [r.value for r in interns] == [1, 0]
     assert [r.value for r in telemetry.records("engine.bypass", t0, t1)[0]] \
         == [2]
