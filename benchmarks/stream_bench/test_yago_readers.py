@@ -1,5 +1,6 @@
-"""The reader of the engine's slot-recycling span (``slot_recycle_ms``)
-on hand-made recorder state whose numbers are known: records inside and outside the window, a ring
+"""The readers of the engine's bypass and slot-recycling spans
+(``bypass_event_ms``, ``slot_recycle_ms``) on hand-made recorder state
+whose numbers are known: records inside and outside the window, a ring
 that dropped records, a program that never recorded the spans, and a
 program without the recorder."""
 import importlib.util
@@ -27,18 +28,25 @@ def _span(start_ms, dur_ms, value=None, parent=None):
 
 
 STATE = {
-    # one recycling on each side of the window, two inside it
+    # three chunks packed nothing (value 0), two reached a dispatch;
+    # one lookup and one bypass lie before the window opens
+    "engine.intern": [_span(-20, 3, 0), _span(10, 1, 0), _span(20, 2, 1),
+                      _span(30, 0.5, 0), _span(40, 4, 1), _span(50, 1, 0)],
+    "engine.bypass": [_span(-17, 5, 1), _span(11, 2, 1), _span(31, 1.5, 1),
+                      _span(51, 1, 1)],
     "engine.recycle": [_span(-100, 50, 3, "service.expire"),
                        _span(100, 6, 110, "service.expire"),
                        _span(600, 2, 0, "service.expire"),
                        _span(2_001, 9, 4, "service.expire")],
 }
 EXPECTED = {
+    "bypass_event_ms": 7 / 3,   # (1 + 0.5 + 1 + 2 + 1.5 + 1) / 3 bypasses
     "slot_recycle_ms": 4.0,     # (6 + 2) / 2 recyclings
 }
 #: the spans each reader reads; a ring of any of them that dropped a
 #: record in the window gives no reading
 READS = {
+    "bypass_event_ms": ("engine.intern", "engine.bypass"),
     "slot_recycle_ms": ("engine.recycle",),
 }
 
@@ -86,3 +94,12 @@ def test_reader_on_hand_made_records(monkeypatch, name):
     monkeypatch.setitem(sys.modules, "repro.telemetry", None)
     assert reader.read(REC) is None
 
+
+def test_bypass_reader_ignores_lookups_of_dispatched_chunks(monkeypatch):
+    """Only the lookups of chunks that packed nothing belong to a bypass:
+    a window of dispatched chunks alone reads nothing."""
+    reader = _reader("bypass_event_ms")
+    _fake_recorder(monkeypatch, {
+        "engine.intern": [_span(10, 5, 1), _span(20, 5, 2)],
+        "engine.bypass": []})
+    assert reader.read(REC) is None
