@@ -129,6 +129,7 @@ from .. import telemetry
 from .automaton import DFA
 from .executor import (
     BatchedEngineArrays,
+    DispatchResult,
     Executor,
     LocalExecutor,
     QueryTables,
@@ -201,14 +202,42 @@ class RegisteredQuery(NamedTuple):
     path_semantics: str = "arbitrary"  # arbitrary | simple
 
 
-def _fetch_result(result_dev) -> np.ndarray:
-    """A dispatch's result mask on the host: wait for the dispatch to
-    finish (``engine.result_wait``), then copy the mask
-    (``engine.result_copy``, valued in bytes)."""
+class FetchedResult(NamedTuple):
+    """A dispatch's result on the host (:func:`_fetch_result`): the
+    compacted rows, and the whole mask when they overflowed."""
+
+    row_ids: np.ndarray           # (R,) int32 q·N + x; -1 pads
+    rows: np.ndarray              # (R, N) bool
+    mask: Optional[np.ndarray]    # (Q, N, N) bool, on overflow only
+
+    def nonzero(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(qs, xs, vs)`` of the set cells, as ``np.nonzero`` of the
+        whole mask gives them (same cells, same order)."""
+        if self.mask is not None:
+            return np.nonzero(self.mask)
+        ri, vs = np.nonzero(self.rows)
+        qs, xs = np.divmod(self.row_ids[ri], self.rows.shape[1])
+        return qs, xs, vs
+
+
+def _fetch_result(result_dev: DispatchResult) -> FetchedResult:
+    """A dispatch's result on the host: wait for the dispatch to finish
+    (``engine.result_wait``), then copy its compacted rows, and the whole
+    mask only if more rows are set than were compacted
+    (``engine.result_copy``, valued in the bytes copied). Counts
+    ``engine.result_fetches`` and ``engine.result_overflows``."""
     with telemetry.span("engine.result_wait"):
-        jax.block_until_ready(result_dev)
-    with telemetry.span("engine.result_copy", result_dev.nbytes):
-        return np.asarray(result_dev)
+        jax.block_until_ready(result_dev.compact)
+    with telemetry.span("engine.result_copy") as sp:
+        n_rows, row_ids, rows = jax.device_get(result_dev.compact)
+        mask = None
+        if int(n_rows) > len(row_ids):
+            mask = np.asarray(result_dev.mask)
+            telemetry.count("engine.result_overflows", 1)
+        sp.value = sum(a.nbytes for a in (n_rows, row_ids, rows)) + (
+            0 if mask is None else mask.nbytes)
+    telemetry.count("engine.result_fetches", 1)
+    return FetchedResult(row_ids, rows, mask)
 
 
 class PendingResults:
@@ -707,9 +736,11 @@ class BatchedDenseRPQEngine:
             return
         invalidated = self.executor.delete_batch(
             src, dst, lab, mask, chunk_now, self.tables)
-        inv = _fetch_result(invalidated)
-        for qi, _spec in self.live_items():
-            out[qi] |= self._decode_pairs(inv[qi], bool(self._simple[qi]))
+        live = {qi for qi, _spec in self.live_items()}
+        for q, p in self._result_pairs(_fetch_result(invalidated).nonzero(),
+                                       self.vertex_of):
+            if q in live:
+                out[q].add(p)
 
     def _bypass(self, n_events: int, chunk_now: float) -> None:
         """A chunk that packed no tuple still advances the stream clock:
@@ -760,9 +791,22 @@ class BatchedDenseRPQEngine:
                 pairs.add((xv, vv))
         return pairs
 
+    def _result_pairs(self, cells, vertex_of: List[Optional[object]]):
+        """``(lane, pair)`` for each set cell ``(qs, xs, vs)`` of a
+        dispatch's result whose endpoints are interned, skipping a simple
+        lane's ``x == v``."""
+        qs, xs, vs = cells
+        for q, x, v in zip(qs.tolist(), xs.tolist(), vs.tolist()):
+            if self._simple[q] and x == v:
+                continue  # a simple path never revisits its source
+            xv = vertex_of[x]
+            vv = vertex_of[v]
+            if xv is not None and vv is not None:
+                yield q, (xv, vv)
+
     def _decode_new_into(
         self,
-        arr: np.ndarray,                       # (Q, N, N) bool
+        arr: FetchedResult,                    # the dispatch's fetched result
         vertex_of: List[Optional[object]],     # interner snapshot at dispatch
         t: float,
         fresh: List[Set[Pair]],
@@ -774,21 +818,15 @@ class BatchedDenseRPQEngine:
         monotonicity.
 
         Spans: ``engine.decode`` (the whole call, valued in new pairs)
-        over ``engine.decode_scan`` (the ``np.nonzero`` scan, valued in
-        cells set)."""
+        over ``engine.decode_scan`` (the ``np.nonzero`` scan of the
+        compacted rows, or of the whole mask on overflow, valued in cells
+        set)."""
         with telemetry.span("engine.decode") as sp:
             with telemetry.span("engine.decode_scan") as scan:
-                qs, xs, vs = np.nonzero(arr)
-                scan.value = len(qs)
+                cells = arr.nonzero()
+                scan.value = len(cells[0])
             added = 0
-            for q, x, v in zip(qs.tolist(), xs.tolist(), vs.tolist()):
-                if self._simple[q] and x == v:
-                    continue
-                xv = vertex_of[x]
-                vv = vertex_of[v]
-                if xv is None or vv is None:
-                    continue
-                p = (xv, vv)
+            for q, p in self._result_pairs(cells, vertex_of):
                 if p not in self.per_query_results[q]:
                     self.per_query_results[q].add(p)
                     self.per_query_log[q].append((t, p))

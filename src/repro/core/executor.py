@@ -182,6 +182,52 @@ def emit_new(arrays: BatchedEngineArrays, dist, adj, now, finals_mask,
     return BatchedEngineArrays(adj, dist, emitted, now), new
 
 
+#: rows of a dispatch's ``(Q, N, N)`` result mask that cross to the host
+#: compacted, an ``(R, N)`` bool payload (256 KB at N = 1024). A frontier
+#: dispatch re-derives a few dozen rows; a result with more set rows than
+#: this crosses whole instead (the overflow path of :func:`compact_rows`).
+RESULT_ROWS = 256
+
+
+class CompactRows(NamedTuple):
+    """The set rows of a ``(Q, N, N)`` bool mask, gathered on the device
+    by :func:`compact_rows`."""
+
+    n_rows: jnp.ndarray   # () int32: rows (q, x) with any bit set
+    row_ids: jnp.ndarray  # (R,) int32: the first R of them as q·N + x; -1 pads
+    rows: jnp.ndarray     # (R, N) bool: those rows (padding rows all False)
+
+
+class DispatchResult(NamedTuple):
+    """A dispatch's result (new or invalidated pairs) as the engine
+    fetches it: the host copies ``compact``; ``mask`` stays on the device
+    and crosses only when ``compact.n_rows`` exceeds the compacted rows
+    (overflow)."""
+
+    compact: CompactRows
+    mask: jnp.ndarray     # (Q, N, N) bool: the whole mask
+
+
+def compact_rows(mask: jnp.ndarray) -> CompactRows:
+    """Row-compact a ``(Q, N, N)`` bool mask: the set rows' ids in
+    ascending ``q·N + x`` order and the first ``min(RESULT_ROWS, Q·N)`` of
+    those rows gathered, so decoding ``rows`` through ``row_ids`` gives
+    the mask's ``np.nonzero`` in its order whenever ``n_rows`` fits. The
+    epilogue of every ingest and delete dispatch, on both executors;
+    ``RESULT_ROWS`` is read when the dispatch traces."""
+    q, n, _ = mask.shape
+    with jax.named_scope("compact_rows"):
+        flat = mask.reshape(q * n, n)
+        row_any = jnp.any(flat, axis=-1)
+        n_rows = jnp.sum(row_any, dtype=jnp.int32)
+        (row_ids,) = jnp.nonzero(row_any, size=min(RESULT_ROWS, q * n),
+                                 fill_value=-1)
+        row_ids = row_ids.astype(jnp.int32)
+        rows = jnp.where((row_ids >= 0)[:, None],
+                         flat[jnp.maximum(row_ids, 0)], False)
+    return CompactRows(n_rows, row_ids, rows)
+
+
 @functools.partial(jax.jit, static_argnames=("backend",), donate_argnums=(0,))
 def _ingest(
     arrays: BatchedEngineArrays,
@@ -204,7 +250,7 @@ def _ingest(
         now=now, w_max=w_max,
     )
     out, new = emit_new(arrays, dist, adj, now, finals_mask, windows)
-    return out, new, rounds, qrounds
+    return out, new, rounds, qrounds, compact_rows(new)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "f_cap"),
@@ -236,7 +282,7 @@ def _ingest_frontier(
         query_mask=live_mask, now=now, w_max=w_max,
     )
     out, new = emit_new(arrays, dist, adj, now, finals_mask, windows)
-    return out, new, rounds, qrounds, fstats
+    return out, new, rounds, qrounds, fstats, compact_rows(new)
 
 
 @functools.partial(jax.jit, static_argnames=("backend",), donate_argnums=(0,))
@@ -272,7 +318,7 @@ def _delete(
     valid_after = batched_valid_pairs(dist, finals_mask, low)
     invalidated = jnp.logical_and(valid_before, jnp.logical_not(valid_after))
     return (BatchedEngineArrays(adj, dist, arrays.emitted, now),
-            invalidated, rounds, qrounds)
+            invalidated, rounds, qrounds, compact_rows(invalidated))
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "f_cap"),
@@ -309,7 +355,8 @@ def _delete_frontier(
     valid_after = batched_valid_pairs(dist, finals_mask, low)
     invalidated = jnp.logical_and(valid_before, jnp.logical_not(valid_after))
     return (BatchedEngineArrays(adj, dist, arrays.emitted, now),
-            invalidated, rounds, qrounds, fstats)
+            invalidated, rounds, qrounds, fstats,
+            compact_rows(invalidated))
 
 
 @jax.jit
@@ -636,8 +683,10 @@ class Executor:
     def ingest_batch(self, src, dst, lab, ts, mask, ts_floor: float,
                      tables: QueryTables):
         """One jitted ingest dispatch for the whole query group. Returns the
-        per-query NEW-validity matrix as a DEVICE array (the engine decodes
-        it, possibly deferred so the transfer overlaps the next dispatch).
+        per-query NEW-validity matrix as a :class:`DispatchResult` of
+        DEVICE arrays (the engine copies its compacted rows and decodes
+        them, possibly deferred so the transfer overlaps the next
+        dispatch).
 
         With ``frontier != "off"`` the dispatch is the frontier-restricted
         one: per-event work scales with the rows the batch dirties, not N
@@ -657,7 +706,7 @@ class Executor:
 
     def _ingest_dispatch(self, src, dst, lab, ts, mask, ts_floor: float,
                          tables: QueryTables):
-        self._arrays, new, rounds, qrounds = _ingest(
+        self._arrays, new, rounds, qrounds, compact = _ingest(
             self._arrays,
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
             jnp.asarray(ts), jnp.asarray(mask),
@@ -668,11 +717,12 @@ class Executor:
         )
         self._account(rounds, qrounds, tables.n_live)
         self.steps += 1
-        return new
+        return DispatchResult(compact, new)
 
     def _ingest_frontier_dispatch(self, src, dst, lab, ts, mask,
                                   ts_floor: float, tables: QueryTables):
-        self._arrays, new, rounds, qrounds, fstats = _ingest_frontier(
+        (self._arrays, new, rounds, qrounds, fstats,
+         compact) = _ingest_frontier(
             self._arrays,
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
             jnp.asarray(ts), jnp.asarray(mask),
@@ -683,12 +733,12 @@ class Executor:
         )
         self._account(rounds, qrounds, tables.n_live, fstats)
         self.steps += 1
-        return new
+        return DispatchResult(compact, new)
 
     def delete_batch(self, src, dst, lab, mask, ts_now: float,
                      tables: QueryTables):
         """Explicit deletion dispatch; returns the invalidated-pairs matrix
-        (device).
+        as a :class:`DispatchResult` (device).
 
         With ``frontier != "off"`` the dispatch is the cone-seeded
         incremental one: only rows whose derivations can pass through the
@@ -706,7 +756,7 @@ class Executor:
 
     def _delete_dispatch(self, src, dst, lab, mask, ts_now: float,
                          tables: QueryTables):
-        self._arrays, invalidated, rounds, qrounds = _delete(
+        self._arrays, invalidated, rounds, qrounds, compact = _delete(
             self._arrays,
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
             jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
@@ -716,11 +766,12 @@ class Executor:
         )
         self._account(rounds, qrounds, tables.n_live)
         self.steps += 1
-        return invalidated
+        return DispatchResult(compact, invalidated)
 
     def _delete_frontier_dispatch(self, src, dst, lab, mask, ts_now: float,
                                   tables: QueryTables):
-        self._arrays, invalidated, rounds, qrounds, fstats = _delete_frontier(
+        (self._arrays, invalidated, rounds, qrounds, fstats,
+         compact) = _delete_frontier(
             self._arrays,
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
             jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
@@ -730,7 +781,7 @@ class Executor:
         )
         self._account(rounds, qrounds, tables.n_live, fstats, is_delete=True)
         self.steps += 1
-        return invalidated
+        return DispatchResult(compact, invalidated)
 
     def relax(self, tables: QueryTables,
               query_mask: Optional[np.ndarray] = None) -> None:

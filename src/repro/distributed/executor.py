@@ -59,9 +59,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import telemetry
 from ..core.executor import (
     BatchedEngineArrays,
+    CompactRows,
+    DispatchResult,
     Executor,
     QueryTables,
     apply_batch,
+    compact_rows,
     drop_batch,
     emit_new,
 )
@@ -404,6 +407,13 @@ def batched_round_lowering(mesh: Mesh, btt: BatchedTransitionTable,
             (dist_sh, adj_sh, mask_sh, scalar_sh, scalar_sh), dist_sh)
 
 
+def _compact_shardings(mesh: Mesh) -> CompactRows:
+    """A dispatch's compacted result rows are replicated: the host reads
+    them whole (the full mask keeps the lane sharding of ``emitted``)."""
+    rep = NamedSharding(mesh, P())
+    return CompactRows(rep, rep, rep)
+
+
 @functools.lru_cache(maxsize=None)
 def _mesh_step_fns(mesh: Mesh, q_axes: Tuple[str, ...], backend,
                    adj_layout: str = "dense", dist_layout: str = "dense"):
@@ -429,6 +439,7 @@ def _mesh_step_fns(mesh: Mesh, q_axes: Tuple[str, ...], backend,
     closure = make_sharded_closure(mesh, backend, q_axes=q_axes)
     state_sh = BatchedEngineArrays(sh["adj"], sh["dist"], sh["emitted"], sh["now"])
     lane_sh = NamedSharding(mesh, P(qa))
+    compact_sh = _compact_shardings(mesh)
 
     def ingest_impl(arrays, src, dst, lab, ts, mask, ts_floor,
                     rows, finals_mask, windows, live_mask, w_max):
@@ -439,7 +450,7 @@ def _mesh_step_fns(mesh: Mesh, q_axes: Tuple[str, ...], backend,
             w_max)
         out, new = emit_new(arrays, dist, adj, now, finals_mask, windows)
         out = out._replace(dist=_dist_like(arrays.dist, dist))
-        return out, new, shard_rounds, qrounds
+        return out, new, shard_rounds, qrounds, compact_rows(new)
 
     def delete_impl(arrays, src, dst, lab, mask, ts_now,
                     rows, finals_mask, windows, live_mask, w_max):
@@ -456,7 +467,7 @@ def _mesh_step_fns(mesh: Mesh, q_axes: Tuple[str, ...], backend,
         invalidated = jnp.logical_and(valid_before, jnp.logical_not(valid_after))
         return (BatchedEngineArrays(adj, _dist_like(arrays.dist, dist),
                                     arrays.emitted, now),
-                invalidated, shard_rounds, qrounds)
+                invalidated, shard_rounds, qrounds, compact_rows(invalidated))
 
     def relax_impl(arrays, rows, query_mask, w_max):
         adj_d = _adj_dense(arrays.adj)
@@ -469,9 +480,11 @@ def _mesh_step_fns(mesh: Mesh, q_axes: Tuple[str, ...], backend,
     return dict(
         shardings=sh,
         ingest=jax.jit(ingest_impl, donate_argnums=(0,),
-                       out_shardings=(state_sh, sh["emitted"], lane_sh, lane_sh)),
+                       out_shardings=(state_sh, sh["emitted"], lane_sh, lane_sh,
+                                      compact_sh)),
         delete=jax.jit(delete_impl, donate_argnums=(0,),
-                       out_shardings=(state_sh, sh["emitted"], lane_sh, lane_sh)),
+                       out_shardings=(state_sh, sh["emitted"], lane_sh, lane_sh,
+                                      compact_sh)),
         relax=jax.jit(relax_impl, donate_argnums=(0,),
                       out_shardings=(state_sh, lane_sh, lane_sh)),
     )
@@ -503,12 +516,14 @@ def _mesh_frontier_ingest(mesh: Mesh, q_axes: Tuple[str, ...], backend,
             mask, now, w_max)
         out, new = emit_new(arrays, dist, adj, now, finals_mask, windows)
         out = out._replace(dist=_dist_like(arrays.dist, dist))
-        return out, new, shard_rounds, qrounds, rr, fb, seed, mx
+        return (out, new, shard_rounds, qrounds, rr, fb, seed, mx,
+                compact_rows(new))
 
     return jax.jit(
         ingest_impl, donate_argnums=(0,),
         out_shardings=(state_sh, sh["emitted"], lane_sh, lane_sh,
-                       lane_sh, lane_sh, lane_sh, lane_sh))
+                       lane_sh, lane_sh, lane_sh, lane_sh,
+                       _compact_shardings(mesh)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -543,12 +558,14 @@ def _mesh_frontier_delete(mesh: Mesh, q_axes: Tuple[str, ...], backend,
                                       jnp.logical_not(valid_after))
         return (BatchedEngineArrays(adj, _dist_like(arrays.dist, dist),
                                     arrays.emitted, now),
-                invalidated, shard_rounds, qrounds, rr, fb, seed, mx)
+                invalidated, shard_rounds, qrounds, rr, fb, seed, mx,
+                compact_rows(invalidated))
 
     return jax.jit(
         delete_impl, donate_argnums=(0,),
         out_shardings=(state_sh, sh["emitted"], lane_sh, lane_sh,
-                       lane_sh, lane_sh, lane_sh, lane_sh))
+                       lane_sh, lane_sh, lane_sh, lane_sh,
+                       _compact_shardings(mesh)))
 
 
 class MeshExecutor(Executor):
@@ -637,7 +654,7 @@ class MeshExecutor(Executor):
                     self.mesh, self.q_axes, self.backend, self.frontier_cap,
                     self.adj_layout, self.dist_layout)
                 (self._arrays, new, shard_rounds, qrounds,
-                 rr, fb, seed, mx) = ingest(
+                 rr, fb, seed, mx, compact) = ingest(
                     self._arrays,
                     jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
                     jnp.asarray(ts), jnp.asarray(mask),
@@ -648,8 +665,9 @@ class MeshExecutor(Executor):
                 self._account(shard_rounds, qrounds, tables.n_live,
                               FrontierStats(seed, mx, rr, fb))
                 self.steps += 1
-                return new
-            self._arrays, new, shard_rounds, qrounds = self._jit_ingest(
+                return DispatchResult(compact, new)
+            (self._arrays, new, shard_rounds, qrounds,
+             compact) = self._jit_ingest(
                 self._arrays,
                 jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
                 jnp.asarray(ts), jnp.asarray(mask),
@@ -659,7 +677,7 @@ class MeshExecutor(Executor):
             )
             self._account(shard_rounds, qrounds, tables.n_live)
             self.steps += 1
-            return new
+            return DispatchResult(compact, new)
 
     def delete_batch(self, src, dst, lab, mask, ts_now: float,
                      tables: QueryTables):
@@ -674,7 +692,7 @@ class MeshExecutor(Executor):
                     self.mesh, self.q_axes, self.backend, self.frontier_cap,
                     self.adj_layout, self.dist_layout)
                 (self._arrays, invalidated, shard_rounds, qrounds,
-                 rr, fb, seed, mx) = delete(
+                 rr, fb, seed, mx, compact) = delete(
                     self._arrays,
                     jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
                     jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
@@ -684,8 +702,9 @@ class MeshExecutor(Executor):
                 self._account(shard_rounds, qrounds, tables.n_live,
                               FrontierStats(seed, mx, rr, fb), is_delete=True)
                 self.steps += 1
-                return invalidated
-            self._arrays, invalidated, shard_rounds, qrounds = self._jit_delete(
+                return DispatchResult(compact, invalidated)
+            (self._arrays, invalidated, shard_rounds, qrounds,
+             compact) = self._jit_delete(
                 self._arrays,
                 jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lab),
                 jnp.asarray(mask), jnp.asarray(ts_now, jnp.float32),
@@ -694,7 +713,7 @@ class MeshExecutor(Executor):
             )
             self._account(shard_rounds, qrounds, tables.n_live)
             self.steps += 1
-            return invalidated
+            return DispatchResult(compact, invalidated)
 
     def relax(self, tables: QueryTables,
               query_mask: Optional[np.ndarray] = None) -> None:
